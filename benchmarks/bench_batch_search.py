@@ -267,6 +267,8 @@ def run(n: int = common.N_SERIES, length: int = common.LENGTH,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="seconds-scale smoke run (no baseline update)")

@@ -145,6 +145,8 @@ def run(quick: bool = False, out_json: str = OUT_JSON
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="seconds-scale smoke run (no baseline update)")

@@ -265,6 +265,8 @@ def _load_previous(out_json: str) -> dict | None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="seconds-scale smoke run (no baseline update)")

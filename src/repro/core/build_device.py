@@ -116,8 +116,8 @@ def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
         paa = np.asarray(paa_j, np.float32)
         sax = np.asarray(sax_j).astype(np.uint8)
     elif encoder == "pallas":
-        from ..kernels.sax_encode import sax_encode as sax_encode_pl
-        paa_j, sax_j = sax_encode_pl(db_dev, w=w, b=b)
+        from ..kernels import ops
+        paa_j, sax_j = ops.sax_encode(db_dev, w, b)
         paa = np.asarray(paa_j, np.float32)
         sax = np.asarray(sax_j).astype(np.uint8)
     else:

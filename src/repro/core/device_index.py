@@ -12,13 +12,13 @@ search impossible to express.  ``DeviceIndex`` unifies it:
   ``S`` contiguous groups cut only at leaf boundaries (so every leaf pack
   stays contiguous inside one shard) and each shard is padded to the common
   row count ``Tp`` (pad rows: ``alive=False``, ``id=-1``, zero series);
-* per-shard leaf MINDIST envelopes and the fixed-size span schedule
-  (windows + (leaf, window)-intersection edges) are precomputed so each
-  shard can run the windowed-pruning loop locally — the same envelope
-  tables serve both metrics (the interval MINDIST of ``core.metric``
-  compares them against the query PAA for ED and against the query's
-  LB_Keogh envelope summary for DTW, so no DTW-specific leaf state is
-  uploaded);
+* each shard's local-to-global leaf id table and its fixed-size span
+  schedule (windows + (leaf, window)-intersection edges) are precomputed
+  so each shard can run the windowed-pruning loop locally on the leaf
+  bounds of the one replicated envelope table — the same table serves
+  both metrics (the interval MINDIST of ``core.metric`` compares it
+  against the query PAA for ED and against the query's LB_Keogh envelope
+  summary for DTW, so no DTW-specific leaf state is uploaded);
 * the global leaf table (``leaf_start/size`` in flattened ``S·Tp`` row
   coordinates, global lo/hi envelopes) and the flattened routing tables
   serve the batched approximate descent; the sibling routing tables
@@ -41,6 +41,7 @@ with an all-gather (see ``search_device.exact_search_device_batch``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import TYPE_CHECKING
 
@@ -56,8 +57,7 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guard (index.py builds us)
 # Children of the pytree, in flatten order.  ``_SHARDED_FIELDS`` are the
 # ``[S, ...]`` arrays placed over the data axis; the rest replicate.
 _ARRAY_FIELDS = (
-    "db", "alive", "ids",
-    "leaf_lo", "leaf_hi",
+    "db", "alive", "ids", "leaf_gid",
     "win_start", "win_lead", "win_size", "edge_leaf", "edge_win",
     "leaf_start", "leaf_size", "leaf_lo_g", "leaf_hi_g", "inv_order",
     "node_csl", "node_shift", "node_lam",
@@ -67,12 +67,17 @@ _ARRAY_FIELDS = (
     "grp_off", "grp_begin", "grp_end", "grp_lo", "grp_hi",
 )
 _SHARDED_FIELDS = frozenset({
-    "db", "alive", "ids", "leaf_lo", "leaf_hi",
+    "db", "alive", "ids", "leaf_gid",
     "win_start", "win_lead", "win_size", "edge_leaf", "edge_win",
 })
 _META_FIELDS = ("n", "w", "chunk", "depth", "lmax", "total",
                 "has_duplicates", "max_replica", "row_bounds",
-                "gmax", "leaf_bounds", "shard_health")
+                "gmax", "leaf_bounds", "shard_health", "mesh")
+
+
+def data_axes(mesh):
+    """The mesh axes the ``[S, ...]`` fields shard over."""
+    return ("pod", "data") if "pod" in mesh.axis_names else "data"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,8 +86,7 @@ class DeviceIndex:
     db: jax.Array          # [S, Tp, n] f32 ordered collection (zero pad)
     alive: jax.Array       # [S, Tp] bool tombstone mask (False pad)
     ids: jax.Array         # [S, Tp] i32 original ids (-1 pad)
-    leaf_lo: jax.Array     # [S, Lp, w] f32 per-shard leaf envelopes (+inf pad)
-    leaf_hi: jax.Array     # [S, Lp, w] f32
+    leaf_gid: jax.Array    # [S, Lp] i32 local leaf -> global leaf id (-1 pad)
     win_start: jax.Array   # [S, W] i32 span schedule (clamped starts)
     win_lead: jax.Array    # [S, W] i32 masked prefix of end-clamped spans
     win_size: jax.Array    # [S, W] i32 live rows per span (0 = pad span)
@@ -133,6 +137,10 @@ class DeviceIndex:
     # aux data, not an array: health changes are rare, and keeping it out
     # of the children means the all-healthy jit cache entries never churn.
     shard_health: tuple | None = None
+    # the mesh the index is placed on (``shard``), ``None`` on one device:
+    # the search programs run their Pallas kernels inside ``shard_map`` over
+    # it, since XLA cannot partition a Mosaic kernel across chips
+    mesh: object = None
 
     # -- shapes --------------------------------------------------------------
     @property
@@ -159,7 +167,8 @@ class DeviceIndex:
     # -- construction --------------------------------------------------------
     @classmethod
     def from_index(cls, index: "DumpyIndex", chunk: int = 2048,
-                   n_shards: int = 1, *, db_device=None) -> "DeviceIndex":
+                   n_shards: int = 1, *, db_device=None,
+                   mesh=None) -> "DeviceIndex":
         """Build the full device state from a host ``DumpyIndex``.
 
         ``n_shards`` fixes the leading axis; the shard boundaries are the
@@ -170,6 +179,10 @@ class DeviceIndex:
         in leaf-contiguous order (the device build's gather output): the data
         plane is then assembled on device and the host ``db_ordered``
         permutation is never materialized.
+
+        ``mesh`` — place the result on it (:meth:`shard`).  The ``[S, Tp,
+        n]`` slab goes shard by shard straight to the devices that own each
+        shard, so no single device ever holds the whole padded slab.
         """
         flat = index.flat
         offs = np.asarray(flat.leaf_offsets, np.int64)
@@ -199,8 +212,7 @@ class DeviceIndex:
         db_sh = np.zeros((S, Tp, n), np.float32)
         alive_sh = np.zeros((S, Tp), bool)
         ids_sh = np.full((S, Tp), -1, np.int32)
-        lo_sh = np.full((S, Lp, w), np.inf, np.float32)
-        hi_sh = np.full((S, Lp, w), np.inf, np.float32)
+        gid_sh = np.full((S, Lp), -1, np.int32)
         win_start = np.zeros((S, W), np.int32)
         win_lead = np.zeros((S, W), np.int32)
         win_size = np.zeros((S, W), np.int32)
@@ -217,8 +229,7 @@ class DeviceIndex:
                 db_sh[s, :Ts] = index.db_ordered[r0:r1]
             alive_sh[s, :Ts] = alive_ord[r0:r1]
             ids_sh[s, :Ts] = order[r0:r1]
-            lo_sh[s, :l1 - l0] = flat.leaf_lo[l0:l1]
-            hi_sh[s, :l1 - l0] = flat.leaf_hi[l0:l1]
+            gid_sh[s, :l1 - l0] = np.arange(l0, l1)
             pos_flat[r0:r1] = s * Tp + np.arange(Ts)
             local_offs = offs[l0:l1 + 1] - r0
             el, ew = [], []
@@ -268,19 +279,31 @@ class DeviceIndex:
                                  np.full((gmax, w), np.inf, np.float32)])
         grp_hi = np.concatenate([rt.grp_hi,
                                  np.full((gmax, w), np.inf, np.float32)])
+        db_sharding = None
+        if mesh is not None:
+            db_sharding = NamedSharding(mesh, P(data_axes(mesh), None, None))
         if db_device is None:
-            db_j = jnp.asarray(db_sh)
+            db_j = (jnp.asarray(db_sh) if db_sharding is None
+                    else jax.device_put(db_sh, db_sharding))
+        elif db_sharding is None:
+            parts = [_padded_rows(db_device, row_bounds[s], row_bounds[s + 1],
+                                  Tp) for s in range(S)]
+            db_j = parts[0] if S == 1 else jnp.concatenate(parts)
         else:
-            parts = []
-            for s in range(S):
-                r0, r1 = row_bounds[s], row_bounds[s + 1]
-                parts.append(jnp.pad(db_device[r0:r1],
-                                     ((0, Tp - (r1 - r0)), (0, 0))))
-            db_j = parts[0][None] if S == 1 else jnp.stack(parts)
+            # each device receives only the shard it owns
+            per_dev = []
+            for d, ix in db_sharding.addressable_devices_indices_map(
+                    (S, Tp, n)).items():
+                s = ix[0].start or 0
+                per_dev.append(jax.device_put(
+                    _padded_rows(db_device, row_bounds[s],
+                                 row_bounds[s + 1], Tp), d))
+            db_j = jax.make_array_from_single_device_arrays(
+                (S, Tp, n), db_sharding, per_dev)
         dev = cls(
             db=db_j, alive=jnp.asarray(alive_sh),
             ids=jnp.asarray(ids_sh),
-            leaf_lo=jnp.asarray(lo_sh), leaf_hi=jnp.asarray(hi_sh),
+            leaf_gid=jnp.asarray(gid_sh),
             win_start=jnp.asarray(win_start), win_lead=jnp.asarray(win_lead),
             win_size=jnp.asarray(win_size),
             edge_leaf=jnp.asarray(edge_leaf), edge_win=jnp.asarray(edge_win),
@@ -310,13 +333,15 @@ class DeviceIndex:
             gmax=gmax,
             leaf_bounds=tuple(int(c) for c in cut_leaf),
         )
-        return dev
+        return dev.shard(mesh) if mesh is not None else dev
 
     # -- sharding ------------------------------------------------------------
-    def shardings(self, mesh, axes="data") -> "DeviceIndex":
+    def shardings(self, mesh) -> "DeviceIndex":
         """A DeviceIndex-shaped pytree of NamedShardings: the ``[S, ...]``
-        fields split over ``axes`` on dim 0, everything else replicated.
-        Usable both for ``device_put`` and as jit ``in_shardings``."""
+        fields split over the mesh's :func:`data_axes` on dim 0, everything
+        else replicated.  Usable both for ``device_put`` and as jit
+        ``in_shardings``."""
+        axes = data_axes(mesh)
         axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
         repl = NamedSharding(mesh, P())
         kw = {}
@@ -329,12 +354,11 @@ class DeviceIndex:
                 kw[f] = repl
         return dataclasses.replace(self, **kw)
 
-    def shard(self, mesh, axes: str | tuple = None) -> "DeviceIndex":
+    def shard(self, mesh) -> "DeviceIndex":
         """Place the index on ``mesh``: shards over the data axes (leaf
         aligned by construction), small tables replicated."""
-        if axes is None:
-            axes = (("pod", "data") if "pod" in mesh.axis_names else "data")
-        return jax.device_put(self, self.shardings(mesh, axes))
+        placed = jax.device_put(self, self.shardings(mesh))
+        return dataclasses.replace(placed, mesh=mesh)
 
     # -- incremental state ---------------------------------------------------
     def with_shard_health(self, health) -> "DeviceIndex":
@@ -371,6 +395,14 @@ class DeviceIndex:
         return dataclasses.replace(self, alive=arr)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _padded_rows(db: jax.Array, r0: int, r1: int, Tp: int) -> jax.Array:
+    """Rows ``[r0, r1)`` of a ``[total, n]`` device array as one zero-padded
+    ``[1, Tp, n]`` shard — one program, so the only new buffer is the shard
+    itself (eager slice + pad + reshape would each copy it)."""
+    return jnp.pad(db[r0:r1], ((0, Tp - (r1 - r0)), (0, 0)))[None]
+
+
 def _flatten(dev: DeviceIndex):
     return (tuple(getattr(dev, f) for f in _ARRAY_FIELDS),
             tuple(getattr(dev, f) for f in _META_FIELDS))
@@ -388,9 +420,11 @@ def abstract_device_index(n_series: int, length: int, w: int, *,
                           n_shards: int = 1, chunk: int = 4096,
                           n_leaves: int = 4096, lam_max: int = 4,
                           depth: int = 8, gmax: int = 64,
-                          shard_health: tuple | None = None) -> DeviceIndex:
+                          shard_health: tuple | None = None,
+                          mesh=None) -> DeviceIndex:
     """A ShapeDtypeStruct-leaved DeviceIndex for lower/compile dry-runs:
-    equal-sized leaves, evenly divided shards (no data, shapes only)."""
+    equal-sized leaves, evenly divided shards (no data, shapes only).
+    ``mesh`` marks it as placed there, as :meth:`DeviceIndex.shard` does."""
     S = max(int(n_shards), 1)
     Tp = math.ceil(n_series / S)
     Ls = math.ceil(n_leaves / S)
@@ -406,7 +440,7 @@ def abstract_device_index(n_series: int, length: int, w: int, *,
     return DeviceIndex(
         db=sds((S, Tp, length), f32), alive=sds((S, Tp), b8),
         ids=sds((S, Tp), i32),
-        leaf_lo=sds((S, Lp, w), f32), leaf_hi=sds((S, Lp, w), f32),
+        leaf_gid=sds((S, Lp), i32),
         win_start=sds((S, W), i32), win_lead=sds((S, W), i32),
         win_size=sds((S, W), i32),
         edge_leaf=sds((S, E), i32), edge_win=sds((S, E), i32),
@@ -431,5 +465,5 @@ def abstract_device_index(n_series: int, length: int, w: int, *,
         row_bounds=tuple(min(s * Tp, n_series) for s in range(S + 1)),
         gmax=gmax,
         leaf_bounds=tuple(min(s * Ls, n_leaves) for s in range(S + 1)),
-        shard_health=shard_health,
+        shard_health=shard_health, mesh=mesh,
     )

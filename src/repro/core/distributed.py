@@ -34,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import get_mesh, logical_rules, DEFAULT_RULES
 from .build import DumpyParams
+from .device_index import data_axes
 from .index import DumpyIndex
 from .sax import next_bit_codes_jnp, sax_encode_jnp
 
@@ -166,11 +167,10 @@ def lower_search_sharded(mesh, *, n_series: int = 1 << 22, length: int = 256,
                                 _mesh_shards)
 
     met = metric or ED
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
     dev_abs = abstract_device_index(n_series, length, w,
                                     n_shards=_mesh_shards(mesh),
                                     chunk=chunk, n_leaves=n_leaves,
-                                    shard_health=shard_health)
+                                    shard_health=shard_health, mesh=mesh)
     # the same program selection as exact_search_device_batch: DTW with a
     # per-query candidate ordering lowers the lane program
     knn = _exact_knn_lane_sharded if (met.is_dtw and met.order != "shared") \
@@ -178,7 +178,7 @@ def lower_search_sharded(mesh, *, n_series: int = 1 << 22, length: int = 256,
     # close over k/metric: pjit rejects kwargs when in_shardings is given
     search_k = lambda d, prep, q: knn(d, prep, q, k=k, metric=met)
     jitted = jax.jit(search_k,
-                     in_shardings=(dev_abs.shardings(mesh, dp), None, None))
+                     in_shardings=(dev_abs.shardings(mesh), None, None))
     prep_abs = _abstract_prep(q_batch, w, length)
     q_abs = jax.ShapeDtypeStruct((q_batch, length), jnp.float32)
     return jitted.lower(dev_abs, prep_abs, q_abs)
@@ -236,14 +236,14 @@ def lower_search_extended(mesh, *, n_series: int = 1 << 22, length: int = 256,
     from .device_index import abstract_device_index
     from .search_device import _extended_knn_sharded, _mesh_shards
 
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
     dev_abs = abstract_device_index(n_series, length, w,
                                     n_shards=_mesh_shards(mesh),
-                                    chunk=chunk, n_leaves=n_leaves)
+                                    chunk=chunk, n_leaves=n_leaves,
+                                    mesh=mesh)
     search_n = lambda d, prep, sq, q: _extended_knn_sharded(
         d, prep, sq, q, k=k, nbr=nbr, subtree=True, span_cap=n_leaves)
     jitted = jax.jit(search_n,
-                     in_shardings=(dev_abs.shardings(mesh, dp),
+                     in_shardings=(dev_abs.shardings(mesh),
                                    None, None, None))
     prep_abs = _abstract_prep(q_batch, w, length)
     sax_abs = jax.ShapeDtypeStruct((q_batch, w), jnp.int32)
@@ -263,14 +263,14 @@ def lower_search_approx(mesh, *, n_series: int = 1 << 22, length: int = 256,
     from .search_device import _approx_knn_device, _mesh_shards
 
     met = metric or ED
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
     dev_abs = abstract_device_index(n_series, length, w,
                                     n_shards=_mesh_shards(mesh),
-                                    chunk=chunk, n_leaves=n_leaves)
+                                    chunk=chunk, n_leaves=n_leaves,
+                                    mesh=mesh)
     approx_k = lambda d, prep, sq, q: _approx_knn_device(
         d, prep, sq, q, k=k, kk=k, nbr=nbr, metric=met)
     jitted = jax.jit(approx_k,
-                     in_shardings=(dev_abs.shardings(mesh, dp),
+                     in_shardings=(dev_abs.shardings(mesh),
                                    None, None, None))
     prep_abs = _abstract_prep(q_batch, w, length)
     sax_abs = jax.ShapeDtypeStruct((q_batch, w), jnp.int32)
@@ -295,10 +295,10 @@ def lower_search_bucket(mesh, *, n_series: int = 1 << 22, length: int = 256,
     from .metric import default_band
     from .search_device import _bucket_knn_sharded, _mesh_shards
 
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
     dev_abs = abstract_device_index(n_series, length, w,
                                     n_shards=_mesh_shards(mesh),
-                                    chunk=chunk, n_leaves=n_leaves)
+                                    chunk=chunk, n_leaves=n_leaves,
+                                    mesh=mesh)
     band_eff = band if band is not None else default_band(length)
     # has_dtw=True lowers the superset (mixed-metric) variant; the pure-ED
     # sibling is the same program minus the cascade
@@ -306,7 +306,7 @@ def lower_search_bucket(mesh, *, n_series: int = 1 << 22, length: int = 256,
         d, pe, pd, sq, q, ln, ld, kk=k, nbr_max=nbr, subtree=True,
         band=band_eff, span_cap=n_leaves, has_dtw=True)
     jitted = jax.jit(search_b,
-                     in_shardings=(dev_abs.shardings(mesh, dp),
+                     in_shardings=(dev_abs.shardings(mesh),
                                    None, None, None, None, None, None))
     prep_abs = _abstract_prep(q_batch, w, length)
     sax_abs = jax.ShapeDtypeStruct((q_batch, w), jnp.int32)
@@ -338,8 +338,7 @@ def lower_search_oneshot(mesh, *, n_series: int = 1 << 22, length: int = 256,
     """Lower the one-shot LB-scan + exact-distance search (``search_step``)
     with the collection batch-sharded — the ``dumpy_search`` roofline
     cell."""
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
-    sh = NamedSharding(mesh, P(dp, None))
+    sh = NamedSharding(mesh, P(data_axes(mesh), None))
     db_abs = jax.ShapeDtypeStruct((n_series, length), jnp.float32)
     q_abs = jax.ShapeDtypeStruct((q_batch, length), jnp.float32)
     lo_abs = jax.ShapeDtypeStruct((n_leaves, w), jnp.float32)
@@ -352,8 +351,7 @@ def lower_build_step(mesh, *, n_series: int = 1 << 22, length: int = 256,
                      w: int = 16, b: int = 8):
     """Lower Stage 1 + the root histogram (``build_step``) with the
     collection batch-sharded — the ``dumpy_build`` roofline cell."""
-    dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
-    sh = NamedSharding(mesh, P(dp, None))
+    sh = NamedSharding(mesh, P(data_axes(mesh), None))
     db_abs = jax.ShapeDtypeStruct((n_series, length), jnp.float32)
     jitted = jax.jit(build_step, static_argnums=(1, 2), in_shardings=(sh,))
     return jitted.lower(db_abs, w, b)

@@ -496,10 +496,9 @@ class DumpyIndex:
 
             def _build():
                 failpoint("device.put")
-                dev = DeviceIndex.from_index(self, chunk=chunk,
-                                             n_shards=n_shards,
-                                             db_device=db_device)
-                return dev.shard(mesh) if mesh is not None else dev
+                return DeviceIndex.from_index(self, chunk=chunk,
+                                              n_shards=n_shards,
+                                              db_device=db_device, mesh=mesh)
 
             # transient upload failures (device OOM races, injected faults)
             # are retried with backoff before giving up

@@ -35,10 +35,14 @@ def ed2_batch_jnp(q: jax.Array, xs: jax.Array) -> jax.Array:
     """Squared ED, batched: ``q [Q, n]``, ``xs [m, n]`` → ``[Q, m]``.
 
     Uses the MXU-friendly ``|q|^2 + |x|^2 - 2 q·x`` form (same math as the
-    Pallas ``pairwise_l2`` kernel; this is its oracle path)."""
+    Pallas ``pairwise_l2`` kernel; this is its oracle path).  The product
+    runs at HIGHEST precision: the TPU's default f32 matmul rounds operands
+    to bf16, an error far above the f32 ties the exact-search re-rank
+    slack absorbs."""
     qn = (q * q).sum(axis=-1, keepdims=True)          # [Q, 1]
     xn = (xs * xs).sum(axis=-1)[None, :]              # [1, m]
-    cross = q @ xs.T                                  # [Q, m]  (MXU)
+    cross = jnp.matmul(q, xs.T,                       # [Q, m]  (MXU)
+                       precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(qn + xn - 2.0 * cross, 0.0)
 
 

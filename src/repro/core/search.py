@@ -15,7 +15,7 @@ import numpy as np
 
 from .build import TreeNode
 from .index import DumpyIndex
-from .lb import dtw_np, ed_np, lb_keogh_np, node_bounds_np
+from .lb import dtw_np_batch, ed_np, lb_keogh_np, node_bounds_np
 from .metric import Metric, interval_mindist_np, query_prep_np, resolve
 from .sax import sax_encode_np
 
@@ -47,7 +47,8 @@ def _leaf_candidates(index: DumpyIndex, leaf_id: int) -> tuple[np.ndarray, np.nd
 def _dists(q: np.ndarray, xs: np.ndarray, metric: Metric) -> np.ndarray:
     if not metric.is_dtw:
         return ed_np(q, xs)
-    return np.array([dtw_np(q, x, metric.band) for x in xs])
+    # one DP over the whole candidate block, bitwise ``dtw_np`` per row
+    return dtw_np_batch(q[None, :], xs[None], metric.band)[0]
 
 
 def _merge_topk(heap: list, ids: np.ndarray, dists: np.ndarray, alive: np.ndarray,

@@ -81,8 +81,9 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from .device_index import DeviceIndex
+from .device_index import DeviceIndex, data_axes
 from .index import DumpyIndex
 from .lb import (dtw2_masked_gather_jnp, dtw_np_batch, ed2_batch_jnp,
                  lb_improved2_batch_jnp, lb_keogh2_batch_jnp)
@@ -111,6 +112,32 @@ def _encode_batch(qs: jax.Array, w: int, b: int) -> tuple[jax.Array, jax.Array]:
     if jax.default_backend() == "tpu":
         return ops.sax_encode(qs, w, b)
     return sax_encode_jnp(qs, w, b)
+
+
+def _interval_lb(dev: DeviceIndex, seg_lo: jax.Array, seg_hi: jax.Array,
+                 lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """Squared interval MINDIST ``[Q, R]`` of the query batch to a replicated
+    region table ``lo/hi [R, w]``.  On a mesh-placed index the kernel runs in
+    a replicated ``shard_map`` — XLA cannot partition a Pallas kernel, so
+    every chip computes the small table itself."""
+    f = lambda a, b, c, d: ops.lb_paa_interval(a, b, c, d, dev.n)
+    if dev.mesh is None:
+        return f(seg_lo, seg_hi, lo, hi)
+    # check_vma off: a pallas_call's output type carries no varying-axes
+    # annotation for the checker to verify
+    return jax.shard_map(f, mesh=dev.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(seg_lo, seg_hi, lo, hi)
+
+
+def _shard_vmap(dev: DeviceIndex, fn, *xs):
+    """``jax.vmap(fn)`` over the leading shard axis of ``xs``.  On a
+    mesh-placed index the map runs inside ``shard_map``, so each chip maps
+    its own shards and the Pallas kernels in ``fn`` run chip-locally."""
+    if dev.mesh is None:
+        return jax.vmap(fn)(*xs)
+    spec = P(data_axes(dev.mesh))
+    return jax.shard_map(jax.vmap(fn), mesh=dev.mesh, in_specs=spec,
+                         out_specs=spec, check_vma=False)(*xs)
 
 
 def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
@@ -346,10 +373,18 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
         metric.is_dtw and chunk > DTW_SUB and chunk % DTW_SUB == 0) else 1
     sub_w = chunk // n_sub
 
-    def per_shard(db_s, alive_s, ids_s, lo_s, hi_s,
+    # one kernel call on the replicated global leaf table; each shard
+    # gathers its own leaves' bounds from it
+    lb_g = _interval_lb(dev, seg_lo, seg_hi, dev.leaf_lo_g,
+                        dev.leaf_hi_g)                             # [Q, L] sq
+
+    def per_shard(db_s, alive_s, ids_s, gid_s,
                   w_start, w_lead, w_size, e_leaf, e_win):
         W = w_start.shape[0]
-        lbq = ops.lb_paa_interval(seg_lo, seg_hi, lo_s, hi_s, n)  # [Q, Lp] sq
+        # this shard's leaves (pad leaf: +inf) in local order
+        lbq = jnp.where(gid_s[None, :] >= 0,
+                        jnp.take(lb_g, jnp.maximum(gid_s, 0), axis=1),
+                        jnp.inf)                                   # [Q, Lp]
         # span LB = min over intersecting leaves (exact: it lower-bounds
         # every series the span contains; pad edges hit the +inf pad leaf)
         win_lb = jax.ops.segment_min(lbq[:, e_leaf].T, e_win, num_segments=W,
@@ -408,8 +443,8 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
         _, topd, topi, vis, st = jax.lax.while_loop(cond, body, init)
         return topd, topi, vis, st
 
-    topd, topi, vis, st = jax.vmap(per_shard)(
-        dev.db, dev.alive, dev.ids, dev.leaf_lo, dev.leaf_hi,
+    topd, topi, vis, st = _shard_vmap(
+        dev, per_shard, dev.db, dev.alive, dev.ids, dev.leaf_gid,
         dev.win_start, dev.win_lead, dev.win_size,
         dev.edge_leaf, dev.edge_win)                        # [S, Q, k]
     topd, topi, vis, st = _mask_dead_shards(dev.shard_health,
@@ -816,13 +851,11 @@ def _approx_knn_device(dev: DeviceIndex, prep: tuple, sax_q: jax.Array,
     (``repro.analysis.registry``).  Returns ``(ids [Q,k], d2 [Q,k],
     leaves [Q,nbr])``; a degenerate tree (the root is the only leaf) routes
     every query to leaf 0, exactly as the host path."""
-    lbq = ops.lb_paa_interval(prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g,
-                              dev.n)
+    lbq = _interval_lb(dev, prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g)
     if dev.node_lam.shape[0] == 0:   # degenerate tree: the root is the only leaf
         routed = jnp.zeros(qs.shape[0], jnp.int32)
     else:
-        edge_lb = ops.lb_paa_interval(prep[0], prep[1], dev.rt_lo, dev.rt_hi,
-                                      dev.n)
+        edge_lb = _interval_lb(dev, prep[0], prep[1], dev.rt_lo, dev.rt_hi)
         routed = _descend_device(
             sax_q, dev.node_csl, dev.node_shift, dev.node_lam,
             dev.rt_parent, dev.rt_sid, dev.rt_leaf, dev.rt_child,
@@ -1031,11 +1064,9 @@ def _extended_knn_sharded(dev: DeviceIndex, prep: tuple,
     schedule is simply every leaf by (LB, leaf id) — the host's
     ``parent is None`` branch.  All bounds are the metric's interval
     MINDIST; ``span_cap`` bounds the per-query schedule sort width."""
-    lbq = ops.lb_paa_interval(prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g,
-                              dev.n)
+    lbq = _interval_lb(dev, prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g)
     if subtree:
-        edge_lb = ops.lb_paa_interval(prep[0], prep[1], dev.rt_lo, dev.rt_hi,
-                                      dev.n)
+        edge_lb = _interval_lb(dev, prep[0], prep[1], dev.rt_lo, dev.rt_hi)
         pm, se = _descend_subtree(dev, sax_q, edge_lb, nbr=nbr)
         leaves = _sibling_schedule(dev, prep, lbq, pm, se, nbr=nbr,
                                    span_cap=span_cap or dev.n_leaves)
@@ -1224,13 +1255,11 @@ def _bucket_knn_sharded(dev: DeviceIndex, prep_ed: tuple, prep_dtw: tuple,
     sel = lane_dtw[:, None]
     prep = tuple(jnp.where(sel, pd, pe)
                  for pe, pd in zip(prep_ed, prep_dtw))
-    lbq = ops.lb_paa_interval(prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g,
-                              dev.n)
+    lbq = _interval_lb(dev, prep[0], prep[1], dev.leaf_lo_g, dev.leaf_hi_g)
     L = dev.n_leaves
     flat = jnp.argsort(lbq, axis=-1)[:, :nbr_max].astype(jnp.int32)
     if subtree:
-        edge_lb = ops.lb_paa_interval(prep[0], prep[1], dev.rt_lo, dev.rt_hi,
-                                      dev.n)
+        edge_lb = _interval_lb(dev, prep[0], prep[1], dev.rt_lo, dev.rt_hi)
         pm, se = _descend_subtree(dev, sax_q, edge_lb, nbr=lane_nbr)
         sub = _sibling_schedule(dev, prep, lbq, pm, se, nbr=nbr_max,
                                 span_cap=span_cap)

@@ -30,14 +30,11 @@ _state = threading.local()
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the running JAX
-    supports them (``jax.sharding.AxisType`` only exists in newer releases;
-    0.4.37 builds meshes with implicit-auto axes, which is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         axis_types=(axis_type.Auto,) * len(tuple(axis_names)))
+    """``jax.make_mesh`` with every axis explicitly ``Auto`` (GSPMD picks
+    the shardings the rules do not pin)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 DEFAULT_RULES: dict[str, Any] = {

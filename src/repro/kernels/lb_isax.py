@@ -35,7 +35,7 @@ def _kernel(qlo_ref, qhi_ref, lo_ref, hi_ref, o_ref, *, scale: float):
 @functools.partial(jax.jit, static_argnames=("n", "tq", "tl", "interpret"))
 def lb_paa_interval(seg_lo: jax.Array, seg_hi: jax.Array, lo: jax.Array,
                     hi: jax.Array, *, n: int, tq: int = 8, tl: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Interval MINDIST: query intervals ``seg_lo/seg_hi [Q, w]`` vs regions
     ``lo/hi [L, w]`` → squared bound ``[Q, L] f32``.
 
@@ -76,7 +76,7 @@ def lb_paa_interval(seg_lo: jax.Array, seg_hi: jax.Array, lo: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("n", "tq", "tl", "interpret"))
 def lb_isax(paa_q: jax.Array, lo: jax.Array, hi: jax.Array, *, n: int,
-            tq: int = 8, tl: int = 512, interpret: bool = True) -> jax.Array:
+            tq: int = 8, tl: int = 512, interpret: bool = False) -> jax.Array:
     """``paa_q [Q, w]``, ``lo/hi [L, w]`` → squared MINDIST ``[Q, L] f32``
     — the degenerate-interval case of :func:`lb_paa_interval` (bitwise
     identical to the historical ED-only kernel)."""

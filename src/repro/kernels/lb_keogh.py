@@ -17,26 +17,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _run_max(x: jax.Array, span: int, forward: bool) -> jax.Array:
+    """Max of the ``span`` lanes starting at each lane (``forward``:
+    ``[i, i+span-1]``; backward: ``[i-span+1, i]``), lanes outside the tile
+    counting as ``-inf``.  Doubling steps of a lane roll + edge mask: no
+    cummax, no lane-splitting reshape (neither lowers on the TPU)."""
+    n = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    neg = jnp.float32(-jnp.inf)
+
+    def shifted(a, k):                 # forward: a[i+k]; backward: a[i-k]
+        if k >= n:
+            return jnp.full_like(a, neg)
+        if forward:
+            return jnp.where(lane < n - k, pltpu.roll(a, n - k, a.ndim - 1),
+                             neg)
+        return jnp.where(lane >= k, pltpu.roll(a, k, a.ndim - 1), neg)
+
+    p = 1
+    while 2 * p <= span:
+        x = jnp.maximum(x, shifted(x, p))
+        p *= 2
+    return x if p == span else jnp.maximum(x, shifted(x, span - p))
 
 
 def _wmax(x: jax.Array, r: int) -> jax.Array:
     """Edge-clamped sliding-window max (window ``[i-r, i+r]``) over the last
-    axis of a ``(TB, n)`` tile — van Herk/Gil–Werman, same contract as
-    :func:`repro.core.lb._window_max` (kept local: kernels stay leaf
-    modules with no ``core`` imports)."""
-    TB, n = x.shape
+    axis of a ``(TB, n)`` tile, the union of the forward and backward
+    ``r+1``-lane runs — same contract as :func:`repro.core.lb._window_max`
+    (kept local: kernels stay leaf modules with no ``core`` imports).  Max
+    is exact, so the result is bitwise that of the jnp twin."""
     if r <= 0:
         return x
-    w = 2 * r + 1
-    nb = -(-(n + r) // w)
-    neg = jnp.full((TB, nb * w - n), -jnp.inf, x.dtype)
-    blocks = jnp.concatenate([x, neg], axis=-1).reshape(TB, nb, w)
-    run = jax.lax.cummax(blocks, axis=2).reshape(TB, nb * w)
-    suf = jnp.flip(jax.lax.cummax(jnp.flip(blocks, -1), axis=2), -1) \
-        .reshape(TB, nb * w)
-    lead = jnp.full((TB, r), -jnp.inf, x.dtype)
-    return jnp.maximum(jnp.concatenate([lead, suf], axis=-1)[:, :n],
-                       run[:, r:r + n])
+    return jnp.maximum(_run_max(x, r + 1, True), _run_max(x, r + 1, False))
 
 
 def _improved_kernel(r, x_ref, q_ref, u_ref, l_ref, o_ref):
@@ -67,7 +83,7 @@ def _kernel(x_ref, u_ref, l_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def lb_keogh(x: jax.Array, U: jax.Array, L: jax.Array, *, block_b: int = 256,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool = False) -> jax.Array:
     """``x [B, n]`` candidates, ``U/L [n]`` query envelope → squared LB [B]."""
     B, n = x.shape
     Bp = -(-B // block_b) * block_b
@@ -93,7 +109,7 @@ def lb_keogh(x: jax.Array, U: jax.Array, L: jax.Array, *, block_b: int = 256,
                    static_argnames=("r", "block_b", "interpret"))
 def lb_improved(x: jax.Array, q: jax.Array, U: jax.Array, L: jax.Array, *,
                 r: int, block_b: int = 256,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Squared LB_Improved (Lemire 2009): ``x [B, n]`` candidates, ``q [n]``
     query, ``U/L [n]`` its envelope, band radius ``r`` → squared LB [B].
 

@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in Python for correctness validation) and False on a
-real TPU backend.  Callers never pass it explicitly.
+On a TPU backend every wrapper runs the compiled Pallas kernel.  Off-TPU
+(CPU test runs) a wrapper either runs the kernel body in Pallas interpret
+mode or, where one fused XLA program is much faster than interpreting the
+grid, the kernel's jnp twin.  The kernels themselves default to
+``interpret=False``; callers of this module never pass it.
 """
 from __future__ import annotations
 

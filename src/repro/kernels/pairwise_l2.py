@@ -24,8 +24,10 @@ def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, k_steps: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    # HIGHEST: a default-precision f32 MXU pass rounds operands to bf16
     o_ref[...] += jnp.dot(q_ref[...], x_ref[...].T,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _fin():
@@ -36,7 +38,7 @@ def _kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, k_steps: int):
 
 @functools.partial(jax.jit, static_argnames=("tq", "tx", "tk", "interpret"))
 def pairwise_l2(q: jax.Array, x: jax.Array, *, tq: int = 128, tx: int = 128,
-                tk: int = 512, interpret: bool = True) -> jax.Array:
+                tk: int = 512, interpret: bool = False) -> jax.Array:
     """``q [Q, n]``, ``x [X, n]`` → squared distances ``[Q, X] f32``.
 
     Inputs are zero-padded to tile multiples (zero padding adds nothing to

@@ -25,7 +25,11 @@ from repro.core.sax import breakpoints
 def _kernel(x_ref, seg_ref, bp_ref, paa_ref, sax_ref, *, w: int, c: int):
     x = x_ref[...]                                   # (TB, n)
     seg = seg_ref[...]                               # (n, w)
-    paa = jnp.dot(x, seg, preferred_element_type=jnp.float32)   # (TB, w) MXU
+    # HIGHEST: the MXU's default f32 pass rounds operands to bf16, which
+    # would shift the PAA by ~0.4% — enough to push the query MINDIST above
+    # the true distance and break exact search
+    paa = jnp.dot(x, seg, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)          # (TB, w) MXU
     paa_ref[...] = paa
     # symbolize: count breakpoints <= paa, in chunks of 128 lanes
     bp = bp_ref[...]                                 # (1, c-1) padded to c
@@ -33,7 +37,7 @@ def _kernel(x_ref, seg_ref, bp_ref, paa_ref, sax_ref, *, w: int, c: int):
     n_chunks = c // 128 if c >= 128 else 1
     chunk = min(c, 128)
     for k in range(n_chunks):
-        blk = jax.lax.dynamic_slice(bp, (0, k * chunk), (1, chunk))  # (1, chunk)
+        blk = bp[:, k * chunk:(k + 1) * chunk]       # (1, chunk), lane-aligned
         # (TB, w, 1) >= (1, 1, chunk) → (TB, w, chunk)
         ge = (paa[:, :, None] >= blk[0][None, None, :]).astype(jnp.int32)
         acc = acc + ge.sum(-1)
@@ -42,7 +46,7 @@ def _kernel(x_ref, seg_ref, bp_ref, paa_ref, sax_ref, *, w: int, c: int):
 
 @functools.partial(jax.jit, static_argnames=("w", "b", "block_b", "interpret"))
 def sax_encode(x: jax.Array, *, w: int, b: int, block_b: int = 256,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """``x [B, n] -> (paa [B, w] f32, sax [B, w] i32)``.
 
     Pads the batch to a multiple of ``block_b``; the breakpoint table is

@@ -380,8 +380,8 @@ def _carry_barrier_fwd(x):
 
 
 def _carry_barrier_bwd(_, g):
-    # identity cotangent: optimization_barrier has no differentiation rule on
-    # JAX 0.4.37, and the barrier is a scheduling hint — the math is identity
+    # identity cotangent: the barrier is a scheduling hint — the math is
+    # identity
     return (g,)
 
 

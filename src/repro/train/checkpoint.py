@@ -31,9 +31,7 @@ def _flatten(tree: Any) -> tuple[list, Any]:
 
 
 def _key_strs(tree: Any) -> list[str]:
-    # jax.tree.flatten_with_path only appears in newer JAX; the tree_util
-    # spelling exists on every supported version (0.4.37+)
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     return ["/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                      for p in path) for path, _ in flat]
 

@@ -1,8 +1,8 @@
 """Property-testing compatibility shim (offline-friendly hypothesis).
 
 The test suite property-tests the iSAX invariants with hypothesis when it is
-installed.  This container has no network access and no ``hypothesis`` wheel,
-so this module degrades ``@given`` / ``strategies`` / ``hypothesis.extra.numpy``
+installed (it is a ``dev`` extra in ``pyproject.toml``).  Where it is not,
+this module degrades ``@given`` / ``strategies`` / ``hypothesis.extra.numpy``
 to deterministic seeded-numpy example sampling with the same call surface:
 
     from _propcheck import given, settings, st, hnp

@@ -68,7 +68,7 @@ def test_f64_injection_trips_policy():
                     (qs.astype(jnp.float64) * 1.0000001).astype(jnp.float32),
                     k=k, metric=metric)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         try:
             sd._exact_knn_sharded = upcast
             bad = contracts.extract_contract(

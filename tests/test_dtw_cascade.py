@@ -99,7 +99,7 @@ def test_ops_lb_improved_kernel_matches_jnp():
     ref = np.asarray(lb_improved2_batch_jnp(
         jnp.asarray(xs), jnp.asarray(q[None, :]), U, L, band))[0]
     got_k = np.asarray(lbk_mod.lb_improved(
-        jnp.asarray(xs), jnp.asarray(q), U[0], L[0], r=band))
+        jnp.asarray(xs), jnp.asarray(q), U[0], L[0], r=band, interpret=True))
     got_o = np.asarray(ops.lb_improved(
         jnp.asarray(xs), jnp.asarray(q), U[0], L[0], band))
     np.testing.assert_array_equal(got_k, ref)

@@ -159,13 +159,15 @@ def test_dtw_band_kernel_sweep(Q, m, n, r, bm):
 
 def test_ops_dtw_band_cpu_fallback_matches_kernel():
     """Off-TPU ``ops.dtw_band`` routes to the jnp anti-diagonal twin; both
-    agree with each other (and the kernel sweep above pins the reference)."""
+    compute the same f32 cell recurrence, so they agree bitwise — masked
+    lanes, cutoff abandons and the TPU tile width (128 lanes) included."""
     from repro.kernels.dtw_band import dtw_band as pallas_dtw
-    qs = jnp.asarray(RNG.standard_normal((2, 64)).astype(np.float32))
-    xs = jnp.asarray(RNG.standard_normal((30, 64)).astype(np.float32))
-    mask = jnp.ones((2, 30), bool)
-    cut = jnp.full((2,), jnp.inf)
+    qs = jnp.asarray(RNG.standard_normal((9, 64)).astype(np.float32))
+    xs = jnp.asarray(RNG.standard_normal((130, 64)).astype(np.float32))
+    mask = jnp.asarray(RNG.random((9, 130)) < 0.7)
+    full = np.asarray(ops.dtw_band(qs, xs, jnp.ones((9, 130), bool),
+                                   jnp.full((9,), jnp.inf), 6))
+    cut = jnp.asarray(np.quantile(full, 0.3, axis=1).astype(np.float32))
     got = np.asarray(ops.dtw_band(qs, xs, mask, cut, 6))
-    want = np.asarray(pallas_dtw(qs, xs, mask, cut, r=6, block_m=16,
-                                 interpret=True))
-    np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-4)
+    want = np.asarray(pallas_dtw(qs, xs, mask, cut, r=6, interpret=True))
+    np.testing.assert_array_equal(got, want)

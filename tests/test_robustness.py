@@ -140,9 +140,10 @@ def test_arm_from_env_spec():
 
 @settings(max_examples=8)
 @given(st.integers(1, 5), st.integers(1, 48))
-def test_wal_roundtrip_property(tmp_path, n_batches, rows):
-    wal = WriteAheadLog(str(tmp_path / f"w-{n_batches}-{rows}.log"))
-    wal.reset()           # examples can repeat (n_batches, rows) pairs
+def test_wal_roundtrip_property(tmp_path_factory, n_batches, rows):
+    # a fresh directory per example: hypothesis runs every example inside
+    # one call of the test, so a function-scoped tmp_path would be shared
+    wal = WriteAheadLog(str(tmp_path_factory.mktemp("wal") / "w.log"))
     rng = np.random.default_rng(n_batches * 100 + rows)
     batches = [rng.normal(size=(rows, 16)).astype(np.float32)
                for _ in range(n_batches)]
