@@ -5,10 +5,11 @@ not per request (``bench_batch_search`` measures steady state on that
 assumption, and ``DumpyIndex._n_device_builds`` already guards the
 device-*state* analogue).  This module guards the device-*program* side:
 
-* :class:`CompileCounter` counts every XLA compile while active, by
-  wrapping ``jax._src.compiler.compile_or_get_cached`` — the single funnel
-  both ``jit`` and ``pjit`` executables pass through (tracing-cache hits
-  never reach it).
+* :class:`CompileCounter` counts every program obtained from the backend
+  while active, compiled or loaded from the persistent cache: it reads the
+  ``dumpy.compile`` records of :mod:`repro.obs`, which hears every call of
+  the compile funnel both ``jit`` and ``pjit`` executables pass through
+  (tracing-cache hits never reach it).
 * :func:`run_sweep` drives the public batched search entry points across a
   k × nbr × metric × batch grid **twice** and reports both passes'
   counts.  The contract: pass 2 adds *zero* compiles (every static/shape
@@ -24,6 +25,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from repro import obs
+
 #: compiles one (metric, k, batch)-combo may cost on its cold pass: the
 #: entry program plus its inner jitted helpers (query prep, encode, LB
 #: kernels, dedup/top-k, finalize).  The default sweep measures ~2.
@@ -35,39 +38,25 @@ class RecompileViolation(AssertionError):
 
 
 class CompileCounter:
-    """Context manager counting XLA compiles (see module docstring).
+    """Context manager counting the programs obtained from the backend
+    between enter and exit (see module docstring), with their names.
 
-    Nesting is safe (each level wraps the current funnel); the count is
-    per-instance.  Not thread-safe — the sweep is single-threaded."""
+    Nesting is safe and the count is per-instance; compiles on other
+    threads inside the scope are counted too."""
 
     def __init__(self) -> None:
         self.count = 0
         self.names: list[str] = []
-        self._orig = None
+        self._start = self._mark = None
 
     def __enter__(self) -> "CompileCounter":
-        from jax._src import compiler as _compiler
-
-        self._orig = _compiler.compile_or_get_cached
-
-        def counted(backend, computation, *args, **kw):
-            self.count += 1
-            try:    # computation is an ir.Module; sym_name is the jit label
-                self.names.append(
-                    computation.operation.attributes["sym_name"].value)
-            except Exception:
-                self.names.append("<unknown>")
-            return orig(backend, computation, *args, **kw)
-
-        orig = self._orig
-        _compiler.compile_or_get_cached = counted
+        self._start, self._mark = obs.compiles(), obs.mark()
         return self
 
     def __exit__(self, *exc) -> None:
-        from jax._src import compiler as _compiler
-
-        _compiler.compile_or_get_cached = self._orig
-        self._orig = None
+        self.count = obs.compiles() - self._start
+        self.names = [s.attrs["fun"] for s in obs.spans()
+                      if s.name == obs.COMPILE and s.sid > self._mark]
 
 
 @dataclass(frozen=True)

@@ -31,6 +31,7 @@ import shutil
 
 import numpy as np
 
+from .. import obs
 from ..robustness.failpoints import failpoint, with_retries
 from ..robustness.wal import WriteAheadLog
 from .build import BuildStats, DumpyBuilder, DumpyParams, TreeNode, collect_leaves
@@ -324,22 +325,25 @@ class DumpyIndex:
         """Build the index with either the host backend (reference Alg. 1
         recursion) or the device backend (bottom-up grouped build,
         ``core/build_device.py``).  Both produce the same layout up to the
-        tie-breaking documented in ``docs/build_pipeline.md``."""
+        tie-breaking documented in ``docs/build_pipeline.md``.  The build
+        is one ``dumpy.build`` span; the device backend's stages are its
+        children (``core/build_device.py``)."""
         params = params or DumpyParams()
-        db = np.ascontiguousarray(db, dtype=np.float32)
-        if backend == "device":
-            from .build_device import device_build
-            res = device_build(db, params)
-            idx = cls(params, res.root, res.flat, db, res.paa, res.sax,
-                      res.stats)
-            idx._db_ordered_dev = res.db_ordered_dev
-            return idx
-        if backend != "host":
-            raise ValueError(f"unknown build backend: {backend!r}")
-        builder = DumpyBuilder(params)
-        root, stats, paa, sax = builder.build(db)
-        flat = flatten_tree(root, params.sax.b)
-        return cls(params, root, flat, db, paa, sax, stats)
+        with obs.span("dumpy.build", backend=backend):
+            db = np.ascontiguousarray(db, dtype=np.float32)
+            if backend == "device":
+                from .build_device import device_build
+                res = device_build(db, params)
+                idx = cls(params, res.root, res.flat, db, res.paa, res.sax,
+                          res.stats)
+                idx._db_ordered_dev = res.db_ordered_dev
+                return idx
+            if backend != "host":
+                raise ValueError(f"unknown build backend: {backend!r}")
+            builder = DumpyBuilder(params)
+            root, stats, paa, sax = builder.build(db)
+            flat = flatten_tree(root, params.sax.b)
+            return cls(params, root, flat, db, paa, sax, stats)
 
     # -- lazy layout ---------------------------------------------------------
     @property
@@ -485,7 +489,11 @@ class DumpyIndex:
         ``alive`` snapshot and refreshed in place without rebuilding the
         layout).  With ``mesh`` the ``[S, ...]`` fields are placed over its
         data axes; the mesh is part of the cache key so the same shard count
-        on a different (or no) mesh never reuses a stale placement."""
+        on a different (or no) mesh never reuses a stale placement.  A
+        cache miss is one ``dumpy.device_index`` span, up to the arrays
+        being ready on the device."""
+        import jax
+
         from .device_index import DeviceIndex
         key = (int(chunk), int(n_shards), mesh)
         cached = self._device_cache.get(key)
@@ -502,7 +510,9 @@ class DumpyIndex:
 
             # transient upload failures (device OOM races, injected faults)
             # are retried with backoff before giving up
-            dev = with_retries(_build, site="device.put")
+            with obs.span("dumpy.device_index"):
+                dev = with_retries(_build, site="device.put")
+                jax.block_until_ready(dev)
             self._n_device_builds += 1
             self._device_cache[key] = (dev, self.alive.copy())
             return dev

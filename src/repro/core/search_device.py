@@ -77,6 +77,7 @@ The DTW exact path ("DTW fast path", docs/device_index.md):
 from __future__ import annotations
 
 import functools
+import itertools
 
 import numpy as np
 import jax
@@ -89,6 +90,7 @@ from .lb import (dtw2_masked_gather_jnp, dtw_np_batch, ed2_batch_jnp,
                  lb_improved2_batch_jnp, lb_keogh2_batch_jnp)
 from .metric import ED, Metric, default_band, query_prep_jnp, resolve
 from .sax import sax_encode_jnp
+from repro import obs
 from repro.kernels import ops
 from repro.robustness.failpoints import failpoint, with_retries
 
@@ -153,6 +155,12 @@ def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
 #: - killed_lb_improved - dp_abandoned`` is derived at the end.
 STAT_KEYS = ("considered", "killed_lb_keogh", "killed_lb_improved",
              "dp_abandoned")
+
+#: slots of the span loop's work vector (i32[3]), recorded per exact call
+#: as ``repro.obs`` counters of these names
+WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed")
+
+_exact_calls = itertools.count()
 
 
 def _cascade_stats(valid: jax.Array, lbk2: jax.Array, lbi2: jax.Array,
@@ -345,11 +353,15 @@ def _dedup_topk(d2: jax.Array, ids: jax.Array, k: int
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                        k: int, metric: Metric = ED
-                       ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+                       ) -> tuple[jax.Array, ...]:
     """Interval-MINDIST tables → per-shard span loops (vmapped) →
     all-gather merge with in-merge dedup.  Returns ``(d [Q,k], original ids
-    [Q,k], spans_visited [Q], cascade stats i32[4])`` with invalid slots as
-    ``inf / -1`` (stats are all-zero for ED).
+    [Q,k], spans_visited [Q], cascade stats i32[4], work i32[3])`` with
+    invalid slots as ``inf / -1`` (stats are all-zero for ED).  ``work``
+    counts the loop's walk in :data:`WORK_KEYS` order, summed over shards
+    (dead ones included: their loops still run): spans walked, their live
+    rows (``w_size``), and live rows × queries active at the span; only
+    the last is carried through the loop.
 
     Early termination is per query *and* per shard: along the shard's span
     order, query q may stop merging at step i iff its suffix-min LB there is
@@ -397,11 +409,11 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
             [suffix, jnp.full((Q, 1), jnp.inf, jnp.float32)], axis=1)
 
         def cond(carry):
-            i, topd, topi, vis, st = carry
+            i, topd, topi, vis, st, pairs = carry
             return (i < W) & jnp.any(suffix[:, i] < topd[:, k - 1])
 
         def body(carry):
-            i, topd, topi, vis, st = carry
+            i, topd, topi, vis, st, pairs = carry
             start = w_start[i]
             qact = win_lb[:, i] < topd[:, k - 1]            # [Q] active mask
 
@@ -433,17 +445,22 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
             else:
                 topd, topi, st = jax.lax.fori_loop(
                     0, n_sub, sub, (topd, topi, st))
-            return i + 1, topd, topi, vis + qact.astype(jnp.int32), st
+            act = qact.astype(jnp.int32)
+            pairs = pairs + w_size[i] * act.sum(dtype=jnp.int32)
+            return i + 1, topd, topi, vis + act, st, pairs
 
         init = (jnp.int32(0),
                 jnp.full((Q, k), jnp.inf, jnp.float32),
                 jnp.full((Q, k), -1, jnp.int32),
                 jnp.zeros((Q,), jnp.int32),
-                jnp.zeros(4, jnp.int32))
-        _, topd, topi, vis, st = jax.lax.while_loop(cond, body, init)
-        return topd, topi, vis, st
+                jnp.zeros(4, jnp.int32),
+                jnp.int32(0))
+        i, topd, topi, vis, st, pairs = jax.lax.while_loop(cond, body, init)
+        # the walk is a prefix of the order: its rows need no carry
+        rows = jnp.where(jnp.arange(W) < i, w_size, 0).sum(dtype=jnp.int32)
+        return topd, topi, vis, st, jnp.stack([i, rows, pairs])
 
-    topd, topi, vis, st = _shard_vmap(
+    topd, topi, vis, st, work = _shard_vmap(
         dev, per_shard, dev.db, dev.alive, dev.ids, dev.leaf_gid,
         dev.win_start, dev.win_lead, dev.win_size,
         dev.edge_leaf, dev.edge_win)                        # [S, Q, k]
@@ -453,7 +470,8 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
     alld = jnp.moveaxis(topd, 0, 1).reshape(Q, S * k)       # all-gather when
     alli = jnp.moveaxis(topi, 0, 1).reshape(Q, S * k)       # sharded over S
     d2m, idm = _dedup_topk(alld, alli, k)
-    return jnp.sqrt(d2m), idm, vis.sum(axis=0), st.sum(axis=0)
+    return (jnp.sqrt(d2m), idm, vis.sum(axis=0), st.sum(axis=0),
+            work.sum(axis=0))
 
 
 def _cluster_groups(Q: int) -> int:
@@ -470,12 +488,12 @@ def _cluster_groups(Q: int) -> int:
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _exact_knn_lane_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                             k: int, metric: Metric
-                            ) -> tuple[jax.Array, jax.Array, jax.Array,
-                                       jax.Array]:
+                            ) -> tuple[jax.Array, ...]:
     """The per-query-ordered DTW exact program (``Metric.order`` ∈
     {"perq", "cluster"}): same contract as :func:`_exact_knn_sharded`
     (``vis`` counts gather-chunks a query was live for, the analogue of
-    spans visited).
+    spans visited), except that it walks no span schedule and so counts
+    no loop work: its ``work`` is ``None``.
 
     Per shard: (1) a lane-chunked precompute builds the full LB_Keogh and
     LB_Improved tables ``[Q, Tp]``; (2) every query argsorts *its own* lanes
@@ -608,7 +626,7 @@ def _exact_knn_lane_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
     alld = jnp.moveaxis(topd, 0, 1).reshape(Q, S * k)
     alli = jnp.moveaxis(topi, 0, 1).reshape(Q, S * k)
     d2m, idm = _dedup_topk(alld, alli, k)
-    return jnp.sqrt(d2m), idm, vis.sum(axis=0), st.sum(axis=0)
+    return jnp.sqrt(d2m), idm, vis.sum(axis=0), st.sum(axis=0), None
 
 
 def _finalize_exact(index: DumpyIndex, qs: np.ndarray, ids_dev: np.ndarray,
@@ -680,41 +698,60 @@ def exact_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
     masked out of the merge, results equal a healthy search restricted to
     the surviving shards' series, and the return tuple gains a trailing
     ``coverage`` float — the fraction of live series still reachable
-    (docs/robustness.md)."""
-    qs = _validate_queries(qs, index.n)
-    met = resolve(metric, qs.shape[1], band, order)
-    if dev is None:
-        dev = index.device_index(chunk=chunk, n_shards=_mesh_shards(mesh),
-                                 mesh=mesh)
-    want_cov = shard_health is not None or dev.shard_health is not None
-    if shard_health is not None:
-        dev = dev.with_shard_health(shard_health)
-    sax = index.params.sax
-    qs_dev = jnp.asarray(qs)
-    prep, _ = _prep_batch(met, qs_dev, sax.w, sax.b)
-    # +8 slack: the loop ranks by f32 device math (the MXU |q|²+|x|²-2qx
-    # form for ED, the f32 band DP for DTW) whose rounding can swap
-    # near-ties across the k boundary; the host re-rank then picks the true
-    # top-k from the widened set
-    kk = _result_margin(dev, k) + 8
-    knn = _exact_knn_lane_sharded if (met.is_dtw and met.order != "shared") \
-        else _exact_knn_sharded
+    (docs/robustness.md).
 
-    def _launch():
-        failpoint("search.shard_merge")
-        return knn(dev, prep, qs_dev, k=kk, metric=met)
+    Each call is a ``dumpy.exact.call`` span (attributes ``call``, ``Q``,
+    ``k``, ``chunk``) over ``dumpy.exact.prep``, ``.launch``, ``.wait``
+    and ``.finalize``, and records the span loop's :data:`WORK_KEYS`
+    counters; the DTW lane program has none (docs/observability.md)."""
+    Q = np.shape(qs)[0] if np.ndim(qs) == 2 else 1
+    with obs.span("dumpy.exact.call", call=next(_exact_calls), Q=Q, k=k,
+                  chunk=chunk if dev is None else dev.chunk):
+        with obs.span("dumpy.exact.prep"):
+            qs = _validate_queries(qs, index.n)
+            met = resolve(metric, qs.shape[1], band, order)
+            if dev is None:
+                dev = index.device_index(chunk=chunk,
+                                         n_shards=_mesh_shards(mesh),
+                                         mesh=mesh)
+            want_cov = shard_health is not None or \
+                dev.shard_health is not None
+            if shard_health is not None:
+                dev = dev.with_shard_health(shard_health)
+            sax = index.params.sax
+            qs_dev = jnp.asarray(qs)
+            prep, _ = _prep_batch(met, qs_dev, sax.w, sax.b)
+        # +8 slack: the loop ranks by f32 device math (the MXU |q|²+|x|²-2qx
+        # form for ED, the f32 band DP for DTW) whose rounding can swap
+        # near-ties across the k boundary; the host re-rank then picks the
+        # true top-k from the widened set
+        kk = _result_margin(dev, k) + 8
+        knn = _exact_knn_lane_sharded if (
+            met.is_dtw and met.order != "shared") else _exact_knn_sharded
 
-    d, ids, visited, st = with_retries(_launch, site="search.shard_merge")
-    ids_out, d_out = _finalize_exact(index, qs, np.asarray(ids), k, met)
-    out = [ids_out, d_out, np.asarray(visited)]
-    if want_cov:
-        out.append(shard_coverage(index, dev))
-    if return_stats:
-        st = np.asarray(st)
-        stats = dict(zip(STAT_KEYS, (int(v) for v in st)))
-        stats["dp_survivors"] = int(st[0] - st[1] - st[2] - st[3])
-        out.append(stats)
-    return tuple(out)
+        def _launch():
+            failpoint("search.shard_merge")
+            return knn(dev, prep, qs_dev, k=kk, metric=met)
+
+        with obs.span("dumpy.exact.launch"):
+            _, ids, visited, st, work = with_retries(
+                _launch, site="search.shard_merge")
+        with obs.span("dumpy.exact.wait"):      # the one host fetch
+            ids, visited, work, st = jax.device_get(
+                (ids, visited, work, st if return_stats else None))
+        if work is not None:
+            for name, v in zip(WORK_KEYS, work):
+                obs.count(name, v)
+        with obs.span("dumpy.exact.finalize"):
+            ids_out, d_out = _finalize_exact(index, qs, ids, k, met)
+        out = [ids_out, d_out, visited]
+        if want_cov:
+            out.append(shard_coverage(index, dev))
+        if return_stats:
+            stats = dict(zip(STAT_KEYS, (int(v) for v in st)))
+            stats["dp_survivors"] = int(st[0] - st[1] - st[2] - st[3])
+            out.append(stats)
+        return tuple(out)
 
 
 def exact_search_device(index: DumpyIndex, q: np.ndarray, k: int,
