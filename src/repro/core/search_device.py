@@ -104,6 +104,8 @@ DTW_LANE_CHUNK = 128
 # lane-chunk width of the LB_Improved table precompute (bounds the
 # [Q, chunk, n] envelope temporaries)
 DTW_LB_CHUNK = 2048
+# vmap axis name of the per-shard span loops (see _shard_vmap)
+SHARD_AXIS = "shard"
 
 
 # ---------------------------------------------------------------------------
@@ -132,13 +134,16 @@ def _interval_lb(dev: DeviceIndex, seg_lo: jax.Array, seg_hi: jax.Array,
 
 
 def _shard_vmap(dev: DeviceIndex, fn, *xs):
-    """``jax.vmap(fn)`` over the leading shard axis of ``xs``.  On a
-    mesh-placed index the map runs inside ``shard_map``, so each chip maps
-    its own shards and the Pallas kernels in ``fn`` run chip-locally."""
+    """``jax.vmap(fn)`` over the leading shard axis of ``xs``, named
+    :data:`SHARD_AXIS`.  On a mesh-placed index the map runs inside
+    ``shard_map``, so each chip maps its own shards and the Pallas kernels
+    in ``fn`` run chip-locally (a reduction over the name then spans the
+    chip's own shards)."""
+    mapped = jax.vmap(fn, axis_name=SHARD_AXIS)
     if dev.mesh is None:
-        return jax.vmap(fn)(*xs)
+        return mapped(*xs)
     spec = P(data_axes(dev.mesh))
-    return jax.shard_map(jax.vmap(fn), mesh=dev.mesh, in_specs=spec,
+    return jax.shard_map(mapped, mesh=dev.mesh, in_specs=spec,
                          out_specs=spec, check_vma=False)(*xs)
 
 
@@ -156,9 +161,10 @@ def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
 STAT_KEYS = ("considered", "killed_lb_keogh", "killed_lb_improved",
              "dp_abandoned")
 
-#: slots of the span loop's work vector (i32[3]), recorded per exact call
+#: slots of the span loop's work vector (i32[4]), recorded per exact call
 #: as ``repro.obs`` counters of these names
-WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed")
+WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed",
+             "exact.spans_merged")
 
 _exact_calls = itertools.count()
 
@@ -356,12 +362,19 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                        ) -> tuple[jax.Array, ...]:
     """Interval-MINDIST tables → per-shard span loops (vmapped) →
     all-gather merge with in-merge dedup.  Returns ``(d [Q,k], original ids
-    [Q,k], spans_visited [Q], cascade stats i32[4], work i32[3])`` with
+    [Q,k], spans_visited [Q], cascade stats i32[4], work i32[4])`` with
     invalid slots as ``inf / -1`` (stats are all-zero for ED).  ``work``
     counts the loop's walk in :data:`WORK_KEYS` order, summed over shards
     (dead ones included: their loops still run): spans walked, their live
-    rows (``w_size``), and live rows × queries active at the span; only
-    the last is carried through the loop.
+    rows (``w_size``), live rows × queries active at the span, and spans
+    at which some candidate entered a query's running top-k; only the last
+    two are carried through the loop.
+
+    Each span (each DTW sub-slab) merges through
+    :func:`ops.topk_merge_cutoff`: nothing when no candidate lies below
+    its query's running k-th best, a short sort of the packed hits when
+    few do, the sort of :func:`ops.topk_merge` otherwise
+    (docs/device_index.md).
 
     Early termination is per query *and* per shard: along the shard's span
     order, query q may stop merging at step i iff its suffix-min LB there is
@@ -409,16 +422,16 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
             [suffix, jnp.full((Q, 1), jnp.inf, jnp.float32)], axis=1)
 
         def cond(carry):
-            i, topd, topi, vis, st, pairs = carry
+            i, topd, topi, vis, st, pairs, merged = carry
             return (i < W) & jnp.any(suffix[:, i] < topd[:, k - 1])
 
         def body(carry):
-            i, topd, topi, vis, st, pairs = carry
+            i, topd, topi, vis, st, pairs, merged = carry
             start = w_start[i]
             qact = win_lb[:, i] < topd[:, k - 1]            # [Q] active mask
 
             def sub(b, c2):
-                topd, topi, st = c2
+                topd, topi, st, hit = c2
                 s0 = start + b * sub_w
                 # pin the literal column index to int32: under an x64 env
                 # a bare 0 defaults to int64 and dynamic_slice rejects the
@@ -435,30 +448,31 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                                       valid[None, :] & qact_b[:, None],
                                       topd[:, k - 1])
                 sid = jax.lax.dynamic_slice(ids_s, (s0,), (sub_w,))
-                idt = jnp.where(jnp.isinf(d2), -1,
-                                jnp.broadcast_to(sid[None, :], (Q, sub_w)))
-                topd, topi = ops.topk_merge(topd, topi, d2, idt)
-                return topd, topi, st + stt
+                topd, topi, m = ops.topk_merge_cutoff(topd, topi, d2, sid,
+                                                      SHARD_AXIS)
+                return topd, topi, st + stt, hit | (m > 0)
 
+            c2 = (topd, topi, st, jnp.bool_(False))
             if n_sub == 1:
-                topd, topi, st = sub(0, (topd, topi, st))
+                topd, topi, st, hit = sub(0, c2)
             else:
-                topd, topi, st = jax.lax.fori_loop(
-                    0, n_sub, sub, (topd, topi, st))
+                topd, topi, st, hit = jax.lax.fori_loop(0, n_sub, sub, c2)
             act = qact.astype(jnp.int32)
             pairs = pairs + w_size[i] * act.sum(dtype=jnp.int32)
-            return i + 1, topd, topi, vis + act, st, pairs
+            return (i + 1, topd, topi, vis + act, st, pairs,
+                    merged + hit.astype(jnp.int32))
 
         init = (jnp.int32(0),
                 jnp.full((Q, k), jnp.inf, jnp.float32),
                 jnp.full((Q, k), -1, jnp.int32),
                 jnp.zeros((Q,), jnp.int32),
                 jnp.zeros(4, jnp.int32),
-                jnp.int32(0))
-        i, topd, topi, vis, st, pairs = jax.lax.while_loop(cond, body, init)
+                jnp.int32(0), jnp.int32(0))
+        i, topd, topi, vis, st, pairs, merged = jax.lax.while_loop(
+            cond, body, init)
         # the walk is a prefix of the order: its rows need no carry
         rows = jnp.where(jnp.arange(W) < i, w_size, 0).sum(dtype=jnp.int32)
-        return topd, topi, vis, st, jnp.stack([i, rows, pairs])
+        return topd, topi, vis, st, jnp.stack([i, rows, pairs, merged])
 
     topd, topi, vis, st, work = _shard_vmap(
         dev, per_shard, dev.db, dev.alive, dev.ids, dev.leaf_gid,

@@ -108,3 +108,99 @@ def topk_merge(topd: jax.Array, topi: jax.Array, d2: jax.Array,
     alli = jnp.concatenate([topi, ids], axis=1)
     neg, sel = jax.lax.top_k(-alld, k)
     return -neg, jnp.take_along_axis(alli, sel, axis=1)
+
+
+#: most candidates a query may bring into :func:`topk_merge_cutoff` for
+#: insertion rounds; beyond it the sort of :func:`topk_merge` runs.  On a
+#: v5e (Q 64, C 2,048) m rounds cost 6 + 2.1·m µs and the sort 86 µs
+#: (PERF.md §6), so rounds win up to m ≈ 38
+MERGE_ROUNDS = 32
+
+_I32_MAX = 0x7FFFFFFF
+
+
+def _flip(b: jax.Array) -> jax.Array:
+    """f32 bits ↔ an i32 key with the total order ``lax.top_k`` sorts by
+    (−0.0 below +0.0, NaNs outside ±inf); the map is its own inverse."""
+    return b ^ ((b >> 31) & _I32_MAX)
+
+
+def _order_key(x: jax.Array) -> jax.Array:
+    return _flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def topk_merge_cutoff(topd: jax.Array, topi: jax.Array, d2: jax.Array,
+                      ids: jax.Array, axis_name: str | None = None
+                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`topk_merge` at a cost that follows what the candidates change.
+
+    ``ids [C]`` are the slab's row ids, shared by every query; a candidate
+    at ``±inf`` takes id ``-1``.  The merged ``(topd, topi)`` is bitwise
+    ``topk_merge(topd, topi, d2, where(isinf(d2), -1, ids))``, and the
+    third output ``m`` is the most candidates any query brings in (capped
+    at ``k``).
+
+    ``top_k`` is stable and the held entries come first, so a candidate
+    enters iff it lies strictly below its query's held ``k``-th entry, and
+    it lands after every held entry it does not lie below.  With ``m == 0``
+    nothing changes.  With ``m <= MERGE_ROUNDS`` each of ``m`` rounds
+    takes every query's least remaining candidate (the lowest column on
+    ties) and inserts it by compare-and-shift.  Beyond that the sort runs.
+    Under ``jax.vmap(..., axis_name=axis_name)`` the branch and the round
+    count follow the largest ``m`` over the mapped axis, so that the map
+    keeps a real conditional (a batched one would run every branch, the
+    sort included); every branch gives the same result, and ``m`` stays
+    the member's own."""
+    Q, k = topd.shape
+    C = d2.shape[1]
+    ids = jnp.asarray(ids)
+    cut = _order_key(topd[:, k - 1:])
+    # the keys are recomputed where they are used, inside the rounds, so
+    # that the conditional takes d2 alone and no [Q, C] temporary
+    m = jnp.minimum((_order_key(d2) < cut).sum(axis=1, dtype=jnp.int32)
+                    .max(), k)
+    m_all = m if axis_name is None else jax.lax.pmax(m, axis_name)
+
+    def keep():
+        return topd, topi
+
+    def insert():
+        col = jnp.arange(C, dtype=jnp.int32)[None, :]
+        slot = jnp.arange(k, dtype=jnp.int32)[None, :]
+
+        def round_(_, c):
+            td, ti, lk, lc = c
+            dk = _order_key(d2)
+            # candidates after the last one taken, in (key, column) order
+            after = (dk > lk[:, None]) | ((dk == lk[:, None])
+                                          & (col > lc[:, None]))
+            key = jnp.where((dk < cut) & after, dk, _I32_MAX)
+            j = jnp.argmin(key, axis=1).astype(jnp.int32)   # first on ties
+            vk = key.min(axis=1)
+            v = jax.lax.bitcast_convert_type(_flip(vk), jnp.float32)
+            vi = jnp.where(jnp.isinf(v), -1, ids[j])
+            tk = _order_key(td)
+            pos = (tk <= vk[:, None]).sum(axis=1, dtype=jnp.int32)[:, None]
+            take = (vk < tk[:, k - 1])[:, None]
+
+            def ins(t, x):
+                prev = jnp.concatenate([t[:, :1], t[:, :-1]], axis=1)
+                new = jnp.where(slot < pos, t,
+                                jnp.where(slot == pos, x[:, None], prev))
+                return jnp.where(take, new, t)
+
+            return ins(td, v), ins(ti, vi), vk, j
+
+        init = (topd, topi, jnp.full((Q,), -_I32_MAX - 1, jnp.int32),
+                jnp.full((Q,), -1, jnp.int32))
+        td, ti, _, _ = jax.lax.fori_loop(0, m_all, round_, init)
+        return td, ti
+
+    def sort():
+        idt = jnp.where(jnp.isinf(d2), -1,
+                        jnp.broadcast_to(ids[None, :], d2.shape))
+        return topk_merge(topd, topi, d2, idt)
+
+    branch = jnp.where(m_all == 0, 0, jnp.where(m_all <= MERGE_ROUNDS, 1, 2))
+    topd, topi = jax.lax.switch(branch, (keep, insert, sort))
+    return topd, topi, m

@@ -167,13 +167,14 @@ def test_exact_call_spans_nest_and_share_the_device_timeline(index,
 def _replay(index, dev, qs, kk):
     """The span loop of ``_exact_knn_sharded`` on the host, per shard, from
     the program's own schedule: ``(spans walked, live rows, live rows ×
-    active queries, visited [Q])`` summed over shards."""
+    active queries, spans at which a candidate entered a running top-k,
+    visited [Q])`` summed over shards."""
     sax = index.params.sax
     prep, _ = sd._prep_batch(sd.ED, jax.numpy.asarray(qs), sax.w, sax.b)
     lb_g = np.asarray(sd._interval_lb(dev, prep[0], prep[1],
                                       dev.leaf_lo_g, dev.leaf_hi_g))
     Q = qs.shape[0]
-    walked = rows = pairs = 0
+    walked = rows = pairs = merged = 0
     vis = np.zeros(Q, np.int64)
     for s in range(dev.db.shape[0]):
         gid = np.asarray(dev.leaf_gid[s])
@@ -201,13 +202,14 @@ def _replay(index, dev, qs, kk):
             slab = db_s[r0:r0 + size[i]]
             d2 = ((qs[:, None, :] - slab[None]) ** 2).sum(-1)
             d2 = np.where(qact[:, None], d2, np.inf)
+            merged += bool((d2 < topd[:, -1:]).any())
             topd = np.sort(np.concatenate([topd, d2], 1), 1)[:, :kk]
             walked += 1
             rows += int(size[i])
             pairs += int(size[i]) * int(qact.sum())
             vis += qact
             i += 1
-    return walked, rows, pairs, vis
+    return walked, rows, pairs, merged, vis
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -222,15 +224,18 @@ def test_loop_counters_equal_a_host_replay(index, n_shards, k, Q):
     _, cnt = _since(m)
     got = {c.name: c.n for c in cnt}
     assert set(got) == set(sd.WORK_KEYS)
-    walked, rows, pairs, vis = _replay(index, dev, qs, k + 8)
+    walked, rows, pairs, merged, vis = _replay(index, dev, qs, k + 8)
     assert (got["exact.spans_walked"], got["exact.rows_live"],
-            got["exact.pairs_needed"]) == (walked, rows, pairs)
+            got["exact.pairs_needed"], got["exact.spans_merged"]) == \
+        (walked, rows, pairs, merged)
     np.testing.assert_array_equal(visited, vis)
     real = int((np.asarray(dev.win_size) > 0).sum())
     if k >= 4000:
         assert walked == real and rows == 4000 and pairs == Q * rows
+        assert merged == walked              # k-th best stays +inf
     elif n_shards == 1:
         assert walked < real                 # the test exercises pruning
+        assert 0 < merged < walked           # and spans that change nothing
 
 
 def test_lane_program_records_no_loop_counters(index):
