@@ -11,14 +11,17 @@ search impossible to express.  ``DeviceIndex`` unifies it:
   ``[S, Tp, n]`` *leaf-aligned* shard layout: leaves are partitioned into
   ``S`` contiguous groups cut only at leaf boundaries (so every leaf pack
   stays contiguous inside one shard) and each shard is padded to the common
-  row count ``Tp`` (pad rows: ``alive=False``, ``id=-1``, zero series);
+  row count ``Tp``, a multiple of 8 (pad rows: ``alive=False``, ``id=-1``,
+  zero series);
 * each shard's local-to-global leaf id table and its fixed-size span
   schedule (windows + (leaf, window)-intersection edges) are precomputed
   so each shard can run the windowed-pruning loop locally on the leaf
   bounds of the one replicated envelope table — the same table serves
   both metrics (the interval MINDIST of ``core.metric`` compares it
   against the query PAA for ED and against the query's LB_Keogh envelope
-  summary for DTW, so no DTW-specific leaf state is uploaded);
+  summary for DTW, so no DTW-specific leaf state is uploaded); the span
+  size is a multiple of 8 rows, so every span starts on an 8-row tile and
+  the span-scan kernel can copy it straight from HBM;
 * the global leaf table (``leaf_start/size`` in flattened ``S·Tp`` row
   coordinates, global lo/hi envelopes) and the flattened routing tables
   serve the batched approximate descent; the sibling routing tables
@@ -204,8 +207,9 @@ class DeviceIndex:
         cut_leaf.append(L)
         row_bounds = tuple(int(offs[c]) for c in cut_leaf)
 
-        Tp = max(max(row_bounds[s + 1] - row_bounds[s] for s in range(S)), 1)
-        chunk_eff = max(min(int(chunk), Tp), 1)
+        Tp = _round_rows(max(row_bounds[s + 1] - row_bounds[s]
+                         for s in range(S)))
+        chunk_eff = min(_round_rows(chunk), Tp)
         W = math.ceil(Tp / chunk_eff)
         Lp = max(cut_leaf[s + 1] - cut_leaf[s] for s in range(S)) + 1  # +pad
 
@@ -416,6 +420,12 @@ def _unflatten(aux, children) -> DeviceIndex:
 jax.tree_util.register_pytree_node(DeviceIndex, _flatten, _unflatten)
 
 
+def _round_rows(rows: int) -> int:
+    """``rows`` rounded up to a whole 8-row tile (at least one): shard and
+    span sizes, so that every span starts on a tile."""
+    return max(-(-int(rows) // 8) * 8, 8)
+
+
 def abstract_device_index(n_series: int, length: int, w: int, *,
                           n_shards: int = 1, chunk: int = 4096,
                           n_leaves: int = 4096, lam_max: int = 4,
@@ -426,10 +436,11 @@ def abstract_device_index(n_series: int, length: int, w: int, *,
     equal-sized leaves, evenly divided shards (no data, shapes only).
     ``mesh`` marks it as placed there, as :meth:`DeviceIndex.shard` does."""
     S = max(int(n_shards), 1)
-    Tp = math.ceil(n_series / S)
+    Ts = math.ceil(n_series / S)
+    Tp = _round_rows(Ts)
     Ls = math.ceil(n_leaves / S)
     Lp = Ls + 1
-    chunk_eff = max(min(int(chunk), Tp), 1)
+    chunk_eff = min(_round_rows(chunk), Tp)
     W = math.ceil(Tp / chunk_eff)
     E = Ls + W
     M = max(n_leaves // 4, 1)
@@ -462,7 +473,7 @@ def abstract_device_index(n_series: int, length: int, w: int, *,
         n=length, w=w, chunk=chunk_eff, depth=depth,
         lmax=max(math.ceil(n_series / max(n_leaves, 1)), 1), total=n_series,
         has_duplicates=False, max_replica=3,
-        row_bounds=tuple(min(s * Tp, n_series) for s in range(S + 1)),
+        row_bounds=tuple(min(s * Ts, n_series) for s in range(S + 1)),
         gmax=gmax,
         leaf_bounds=tuple(min(s * Ls, n_leaves) for s in range(S + 1)),
         shard_health=shard_health, mesh=mesh,

@@ -7,14 +7,15 @@ analogue).  Here the same plan is one XLA program per shard:
     lb        = MINDIST(PAA(q), every local leaf)      (lb_isax math)
     span LB   = segment-min over intersecting leaves
     order     = argsort(min-over-queries span LB)
-    while any query still has an unpruned span:        (lax.while_loop)
-        slab  = dynamic_slice(shard-local collection)  (fixed-size span)
+    while any query still has an unpruned span:        (span_scan kernel)
+        slab  = the next span's rows, HBM → VMEM       (fixed-size span)
         d     = |q - slab|²                            (MXU form, whole batch)
         topk  = merge(topk, d)                         (per-query active mask)
 
-The per-shard loops are vmapped over the leading shard axis of the
-``DeviceIndex``; when that axis carries ``NamedSharding(mesh, P("data"))``
-GSPMD turns the vmap into shard-local execution and the final merge
+The walk is one ``ops.span_scan`` call over the leading shard axis of the
+``DeviceIndex`` (a Pallas kernel on the TPU, an XLA ``while_loop`` off
+it; DTW keeps the loop).  On a mesh it runs inside ``shard_map``, each
+chip over its own shards, and GSPMD turns the final merge
 
     [S, Q, kk] --all-gather--> [Q, S·kk] --dedup+top_k--> [Q, kk]
 
@@ -104,7 +105,7 @@ DTW_LANE_CHUNK = 128
 # lane-chunk width of the LB_Improved table precompute (bounds the
 # [Q, chunk, n] envelope temporaries)
 DTW_LB_CHUNK = 2048
-# vmap axis name of the per-shard span loops (see _shard_vmap)
+# vmap axis name of the per-shard span loops (see span_loop)
 SHARD_AXIS = "shard"
 
 
@@ -133,17 +134,16 @@ def _interval_lb(dev: DeviceIndex, seg_lo: jax.Array, seg_hi: jax.Array,
                          check_vma=False)(seg_lo, seg_hi, lo, hi)
 
 
-def _shard_vmap(dev: DeviceIndex, fn, *xs):
-    """``jax.vmap(fn)`` over the leading shard axis of ``xs``, named
-    :data:`SHARD_AXIS`.  On a mesh-placed index the map runs inside
-    ``shard_map``, so each chip maps its own shards and the Pallas kernels
-    in ``fn`` run chip-locally (a reduction over the name then spans the
-    chip's own shards)."""
-    mapped = jax.vmap(fn, axis_name=SHARD_AXIS)
+def _shard_local(dev: DeviceIndex, fn, *xs):
+    """``fn`` over the ``[S, ...]`` shard arrays ``xs``.  On a mesh-placed
+    index it runs inside ``shard_map``, so each chip sees only its own
+    shards and the Pallas kernels in ``fn`` run chip-locally (a reduction
+    over :data:`SHARD_AXIS` inside ``fn`` then spans the chip's own
+    shards)."""
     if dev.mesh is None:
-        return mapped(*xs)
+        return fn(*xs)
     spec = P(data_axes(dev.mesh))
-    return jax.shard_map(mapped, mesh=dev.mesh, in_specs=spec,
+    return jax.shard_map(fn, mesh=dev.mesh, in_specs=spec,
                          out_specs=spec, check_vma=False)(*xs)
 
 
@@ -161,10 +161,10 @@ def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
 STAT_KEYS = ("considered", "killed_lb_keogh", "killed_lb_improved",
              "dp_abandoned")
 
-#: slots of the span loop's work vector (i32[4]), recorded per exact call
+#: slots of the span walk's work vector (i32[5]), recorded per exact call
 #: as ``repro.obs`` counters of these names
 WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed",
-             "exact.spans_merged")
+             "exact.spans_merged", "exact.merge_rounds")
 
 _exact_calls = itertools.count()
 
@@ -356,126 +356,176 @@ def _dedup_topk(d2: jax.Array, ids: jax.Array, k: int
 # sharded exact search (one XLA program; S=1 is the single-device case)
 # ---------------------------------------------------------------------------
 
+def _walk_schedule(lb_g: jax.Array, gid_s: jax.Array, w_start: jax.Array,
+                   w_lead: jax.Array, w_size: jax.Array, e_leaf: jax.Array,
+                   e_win: jax.Array) -> tuple[jax.Array, ...]:
+    """One shard's walk: from the global leaf bounds ``lb_g [Q, L]`` and
+    the shard's leaf ids and span schedule, the schedule in walk order
+    ``(start, lead, size [W], win_lb [Q, W], suffix [Q, W+1])``: the span
+    bounds, and their suffix minima with a ``+inf`` column appended."""
+    Q = lb_g.shape[0]
+    W = w_start.shape[0]
+    # this shard's leaves (pad leaf: +inf) in local order
+    lbq = jnp.where(gid_s[None, :] >= 0,
+                    jnp.take(lb_g, jnp.maximum(gid_s, 0), axis=1),
+                    jnp.inf)                                       # [Q, Lp]
+    # span LB = min over intersecting leaves (exact: it lower-bounds every
+    # series the span contains; pad edges hit the +inf pad leaf)
+    win_lb = jax.ops.segment_min(lbq[:, e_leaf].T, e_win, num_segments=W,
+                                 indices_are_sorted=True).T      # [Q, W]
+    order = jnp.argsort(win_lb.min(axis=0))       # most promising for anyone
+    win_lb = win_lb[:, order]
+    suffix = jnp.flip(jax.lax.cummin(jnp.flip(win_lb, 1), axis=1), 1)
+    suffix = jnp.concatenate(
+        [suffix, jnp.full((Q, 1), jnp.inf, jnp.float32)], axis=1)
+    return w_start[order], w_lead[order], w_size[order], win_lb, suffix
+
+
+def span_loop(metric: Metric, qs: jax.Array, prep: tuple, db_s: jax.Array,
+              alive_s: jax.Array, ids_s: jax.Array, w_start: jax.Array,
+              w_lead: jax.Array, w_size: jax.Array, win_lb: jax.Array,
+              suffix: jax.Array, *, k: int, chunk: int
+              ) -> tuple[jax.Array, ...]:
+    """One shard's span walk as an XLA ``while_loop``, vmapped over
+    :data:`SHARD_AXIS`: the DTW walk, and off the TPU the jnp twin of the
+    span-scan kernel (``ops.span_scan``).
+
+    ``w_start/w_lead/w_size [W]`` is the walk-ordered schedule, ``win_lb
+    [Q, W]`` the span bounds in that order and ``suffix [Q, W+1]`` their
+    suffix minima with a ``+inf`` column appended.  Returns ``(topd [Q, k],
+    topi [Q, k], vis [Q], cascade stats i32[4], work i32[5])``, ``work`` in
+    :data:`WORK_KEYS` order; only the last three are carried.
+
+    Each span (each DTW sub-slab) merges through
+    :func:`ops.topk_merge_cutoff`.  DTW sub-blocks the slab with an inner
+    ``fori_loop`` over ``DTW_SUB``-wide sub-slabs, which bounds the
+    DP-frontier memory without a second narrow ``DeviceIndex``, and
+    re-reads the running cutoff between sub-blocks, so each sub-slab prunes
+    against everything earlier sub-slabs just merged."""
+    Q = qs.shape[0]
+    W = w_start.shape[0]
+    n = db_s.shape[1]
+    # sub-blocking needs exact tiling; a chunk that is no multiple of
+    # DTW_SUB (or one already at/below it) runs the slab whole
+    n_sub = chunk // DTW_SUB if (
+        metric.is_dtw and chunk > DTW_SUB and chunk % DTW_SUB == 0) else 1
+    sub_w = chunk // n_sub
+
+    def cond(carry):
+        i, topd = carry[0], carry[1]
+        return (i < W) & jnp.any(suffix[:, i] < topd[:, k - 1])
+
+    def body(carry):
+        i, topd, topi, vis, st, pairs, merged, rounds = carry
+        start = w_start[i]
+        qact = win_lb[:, i] < topd[:, k - 1]                # [Q] active mask
+
+        def sub(b, c2):
+            topd, topi, st, hit, rounds = c2
+            s0 = start + b * sub_w
+            # pin the literal column index to int32: under an x64 env a
+            # bare 0 defaults to int64 and dynamic_slice rejects the mixed
+            # index dtypes (audit injection tests lower with x64)
+            slab = jax.lax.dynamic_slice(db_s, (s0, jnp.int32(0)),
+                                         (sub_w, n))
+            j = b * sub_w + jnp.arange(sub_w)               # slab-local rows
+            valid = (j >= w_lead[i]) & (j < w_lead[i] + w_size[i])
+            valid &= jax.lax.dynamic_slice(alive_s, (s0,), (sub_w,))
+            # cutoff re-read each sub-block: later sub-slabs prune against
+            # what earlier ones merged
+            qact_b = qact & (win_lb[:, i] < topd[:, k - 1])
+            d2, stt = _dist2_slab(metric, qs, prep, slab,
+                                  valid[None, :] & qact_b[:, None],
+                                  topd[:, k - 1])
+            sid = jax.lax.dynamic_slice(ids_s, (s0,), (sub_w,))
+            topd, topi, m = ops.topk_merge_cutoff(topd, topi, d2, sid,
+                                                  SHARD_AXIS)
+            return topd, topi, st + stt, hit | (m > 0), rounds + m
+
+        c2 = (topd, topi, st, jnp.bool_(False), rounds)
+        if n_sub == 1:
+            topd, topi, st, hit, rounds = sub(0, c2)
+        else:
+            topd, topi, st, hit, rounds = jax.lax.fori_loop(0, n_sub, sub, c2)
+        act = qact.astype(jnp.int32)
+        pairs = pairs + w_size[i] * act.sum(dtype=jnp.int32)
+        return (i + 1, topd, topi, vis + act, st, pairs,
+                merged + hit.astype(jnp.int32), rounds)
+
+    init = (jnp.int32(0),
+            jnp.full((Q, k), jnp.inf, jnp.float32),
+            jnp.full((Q, k), -1, jnp.int32),
+            jnp.zeros((Q,), jnp.int32),
+            jnp.zeros(4, jnp.int32),
+            jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    i, topd, topi, vis, st, pairs, merged, rounds = jax.lax.while_loop(
+        cond, body, init)
+    # the walk is a prefix of the order: its rows need no carry
+    rows = jnp.where(jnp.arange(W) < i, w_size, 0).sum(dtype=jnp.int32)
+    return topd, topi, vis, st, jnp.stack([i, rows, pairs, merged, rounds])
+
+
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
 def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                        k: int, metric: Metric = ED
                        ) -> tuple[jax.Array, ...]:
-    """Interval-MINDIST tables → per-shard span loops (vmapped) →
-    all-gather merge with in-merge dedup.  Returns ``(d [Q,k], original ids
-    [Q,k], spans_visited [Q], cascade stats i32[4], work i32[4])`` with
-    invalid slots as ``inf / -1`` (stats are all-zero for ED).  ``work``
-    counts the loop's walk in :data:`WORK_KEYS` order, summed over shards
-    (dead ones included: their loops still run): spans walked, their live
-    rows (``w_size``), live rows × queries active at the span, and spans
-    at which some candidate entered a query's running top-k; only the last
-    two are carried through the loop.
+    """Interval-MINDIST tables → per-shard span walks → all-gather merge
+    with in-merge dedup.  Returns ``(d [Q,k], original ids [Q,k],
+    spans_visited [Q], cascade stats i32[4], work i32[5])`` with invalid
+    slots as ``inf / -1`` (stats are all-zero for ED).  ``work`` counts the
+    walk in :data:`WORK_KEYS` order, summed over shards (dead ones
+    included: their walks still run): spans walked, their live rows
+    (``w_size``), live rows × queries active at the span, spans at which
+    some candidate entered a query's running top-k, and the merge's
+    insertion rounds (per span the most candidates any query brings in,
+    capped at ``k``).
 
-    Each span (each DTW sub-slab) merges through
-    :func:`ops.topk_merge_cutoff`: nothing when no candidate lies below
-    its query's running k-th best, a short sort of the packed hits when
-    few do, the sort of :func:`ops.topk_merge` otherwise
-    (docs/device_index.md).
+    Per shard a vmapped prelude builds the span bounds (the metric's
+    interval MINDIST, min over each span's leaves), the walk order shared
+    by the batch (ascending min-over-queries bound) and the suffix minima
+    of the bounds along it.  The walk itself depends on the metric and the
+    backend:
+
+    - **ED on a TPU** (series length a multiple of 128): one
+      ``ops.span_scan`` Pallas kernel for all the chip's shards (grid
+      shards × spans), which streams each span's slab from HBM once and
+      keeps the running top-k in VMEM (docs/device_index.md, "Span
+      scan").  This covers the benchmark's exact path,
+      ``exact_search_device``, degraded mode and the sharded search on a
+      mesh.
+    - **ED otherwise** (off the TPU, or another length): the same call
+      runs :func:`span_loop`, the kernel's jnp twin.
+    - **DTW** (``order="shared"``): :func:`span_loop` with the LB cascade
+      and the band DP as the span body, on every backend.
 
     Early termination is per query *and* per shard: along the shard's span
     order, query q may stop merging at step i iff its suffix-min LB there is
     ≥ its running kth best — every span it has not seen locally is
-    individually prunable.  The loop is metric-generic: the leaf/span bound
-    is the metric's interval MINDIST and the slab distance is
-    :func:`_dist2_slab` (the DTW LB cascade + fused masked band DP).
-
-    DTW runs on the same ED-width layout: the span body sub-blocks the slab
-    with an inner ``fori_loop`` over ``DTW_SUB``-wide sub-slabs, which
-    bounds the DP-frontier memory without a second narrow ``DeviceIndex``,
-    and re-reads the running cutoff between sub-blocks so each sub-slab
-    prunes against everything earlier sub-slabs just merged."""
+    individually prunable — and the walk stops when no query may go on."""
     Q = qs.shape[0]
-    chunk = dev.chunk
-    n = dev.n
     seg_lo, seg_hi = prep[0], prep[1]
-    # sub-blocking needs exact tiling; an odd explicit chunk (or one already
-    # at/below DTW_SUB) just runs the slab whole, as before
-    n_sub = chunk // DTW_SUB if (
-        metric.is_dtw and chunk > DTW_SUB and chunk % DTW_SUB == 0) else 1
-    sub_w = chunk // n_sub
 
     # one kernel call on the replicated global leaf table; each shard
     # gathers its own leaves' bounds from it
     lb_g = _interval_lb(dev, seg_lo, seg_hi, dev.leaf_lo_g,
                         dev.leaf_hi_g)                             # [Q, L] sq
 
-    def per_shard(db_s, alive_s, ids_s, gid_s,
-                  w_start, w_lead, w_size, e_leaf, e_win):
-        W = w_start.shape[0]
-        # this shard's leaves (pad leaf: +inf) in local order
-        lbq = jnp.where(gid_s[None, :] >= 0,
-                        jnp.take(lb_g, jnp.maximum(gid_s, 0), axis=1),
-                        jnp.inf)                                   # [Q, Lp]
-        # span LB = min over intersecting leaves (exact: it lower-bounds
-        # every series the span contains; pad edges hit the +inf pad leaf)
-        win_lb = jax.ops.segment_min(lbq[:, e_leaf].T, e_win, num_segments=W,
-                                     indices_are_sorted=True).T  # [Q, W]
-        order = jnp.argsort(win_lb.min(axis=0))   # most promising for anyone
-        w_start, w_lead, w_size = w_start[order], w_lead[order], w_size[order]
-        win_lb = win_lb[:, order]
-        suffix = jnp.flip(jax.lax.cummin(jnp.flip(win_lb, 1), axis=1), 1)
-        suffix = jnp.concatenate(
-            [suffix, jnp.full((Q, 1), jnp.inf, jnp.float32)], axis=1)
+    def walk(db, alive, ids, *sched):
+        start, lead, size, win_lb, suffix = jax.vmap(
+            functools.partial(_walk_schedule, lb_g))(*sched)
+        if metric.is_dtw:
+            return jax.vmap(
+                functools.partial(span_loop, metric, qs, prep, k=k,
+                                  chunk=dev.chunk),
+                axis_name=SHARD_AXIS)(db, alive, ids, start, lead, size,
+                                      win_lb, suffix)
+        topd, topi, vis, work = ops.span_scan(
+            qs, db, alive, ids, start, lead, size, win_lb, suffix, kk=k,
+            chunk=dev.chunk)
+        return topd, topi, vis, jnp.zeros((db.shape[0], 4), jnp.int32), work
 
-        def cond(carry):
-            i, topd, topi, vis, st, pairs, merged = carry
-            return (i < W) & jnp.any(suffix[:, i] < topd[:, k - 1])
-
-        def body(carry):
-            i, topd, topi, vis, st, pairs, merged = carry
-            start = w_start[i]
-            qact = win_lb[:, i] < topd[:, k - 1]            # [Q] active mask
-
-            def sub(b, c2):
-                topd, topi, st, hit = c2
-                s0 = start + b * sub_w
-                # pin the literal column index to int32: under an x64 env
-                # a bare 0 defaults to int64 and dynamic_slice rejects the
-                # mixed index dtypes (audit injection tests lower with x64)
-                slab = jax.lax.dynamic_slice(db_s, (s0, jnp.int32(0)),
-                                             (sub_w, n))
-                j = b * sub_w + jnp.arange(sub_w)           # slab-local rows
-                valid = (j >= w_lead[i]) & (j < w_lead[i] + w_size[i])
-                valid &= jax.lax.dynamic_slice(alive_s, (s0,), (sub_w,))
-                # cutoff re-read each sub-block: later sub-slabs prune
-                # against what earlier ones merged
-                qact_b = qact & (win_lb[:, i] < topd[:, k - 1])
-                d2, stt = _dist2_slab(metric, qs, prep, slab,
-                                      valid[None, :] & qact_b[:, None],
-                                      topd[:, k - 1])
-                sid = jax.lax.dynamic_slice(ids_s, (s0,), (sub_w,))
-                topd, topi, m = ops.topk_merge_cutoff(topd, topi, d2, sid,
-                                                      SHARD_AXIS)
-                return topd, topi, st + stt, hit | (m > 0)
-
-            c2 = (topd, topi, st, jnp.bool_(False))
-            if n_sub == 1:
-                topd, topi, st, hit = sub(0, c2)
-            else:
-                topd, topi, st, hit = jax.lax.fori_loop(0, n_sub, sub, c2)
-            act = qact.astype(jnp.int32)
-            pairs = pairs + w_size[i] * act.sum(dtype=jnp.int32)
-            return (i + 1, topd, topi, vis + act, st, pairs,
-                    merged + hit.astype(jnp.int32))
-
-        init = (jnp.int32(0),
-                jnp.full((Q, k), jnp.inf, jnp.float32),
-                jnp.full((Q, k), -1, jnp.int32),
-                jnp.zeros((Q,), jnp.int32),
-                jnp.zeros(4, jnp.int32),
-                jnp.int32(0), jnp.int32(0))
-        i, topd, topi, vis, st, pairs, merged = jax.lax.while_loop(
-            cond, body, init)
-        # the walk is a prefix of the order: its rows need no carry
-        rows = jnp.where(jnp.arange(W) < i, w_size, 0).sum(dtype=jnp.int32)
-        return topd, topi, vis, st, jnp.stack([i, rows, pairs, merged])
-
-    topd, topi, vis, st, work = _shard_vmap(
-        dev, per_shard, dev.db, dev.alive, dev.ids, dev.leaf_gid,
+    topd, topi, vis, st, work = _shard_local(
+        dev, walk, dev.db, dev.alive, dev.ids, dev.leaf_gid,
         dev.win_start, dev.win_lead, dev.win_size,
         dev.edge_leaf, dev.edge_win)                        # [S, Q, k]
     topd, topi, vis, st = _mask_dead_shards(dev.shard_health,
@@ -715,15 +765,19 @@ def exact_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
     (docs/robustness.md).
 
     Each call is a ``dumpy.exact.call`` span (attributes ``call``, ``Q``,
-    ``k``, ``chunk``) over ``dumpy.exact.prep``, ``.launch``, ``.wait``
-    and ``.finalize``, and records the span loop's :data:`WORK_KEYS`
-    counters; the DTW lane program has none (docs/observability.md)."""
+    ``k``, ``chunk``, and ``scan``: ``"kernel"`` where the span-scan
+    kernel walks the spans, ``"loop"`` where an XLA loop does) over
+    ``dumpy.exact.prep``, ``.launch``, ``.wait`` and ``.finalize``, and
+    records the span walk's :data:`WORK_KEYS` counters; the DTW lane
+    program has none (docs/observability.md)."""
     Q = np.shape(qs)[0] if np.ndim(qs) == 2 else 1
+    met = resolve(metric, index.n, band, order)
+    scan = "kernel" if ops.span_scan_kernel(index.n) and not met.is_dtw \
+        else "loop"
     with obs.span("dumpy.exact.call", call=next(_exact_calls), Q=Q, k=k,
-                  chunk=chunk if dev is None else dev.chunk):
+                  chunk=chunk if dev is None else dev.chunk, scan=scan):
         with obs.span("dumpy.exact.prep"):
             qs = _validate_queries(qs, index.n)
-            met = resolve(metric, qs.shape[1], band, order)
             if dev is None:
                 dev = index.device_index(chunk=chunk,
                                          n_shards=_mesh_shards(mesh),

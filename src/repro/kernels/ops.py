@@ -8,6 +8,8 @@ grid, the kernel's jnp twin.  The kernels themselves default to
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -16,6 +18,7 @@ from . import lb_isax as _lb
 from . import lb_keogh as _lbk
 from . import pairwise_l2 as _pl2
 from . import sax_encode as _se
+from . import span_scan as _ss
 
 
 def _interpret() -> bool:
@@ -85,6 +88,34 @@ def dtw_band(qs: jax.Array, xs: jax.Array, mask: jax.Array,
         from repro.core.lb import dtw2_masked_batch_jnp
         return dtw2_masked_batch_jnp(qs, xs, r, mask, cutoff2)
     return _dtw.dtw_band(qs, xs, mask, cutoff2, r=r, interpret=False)
+
+
+def span_scan_kernel(n: int) -> bool:
+    """Whether :func:`span_scan` runs the Pallas kernel for series of
+    length ``n``: on a TPU, where whole 128-lane rows can be copied (a DMA
+    of a narrower minor dimension does not compile), else its jnp twin."""
+    return not _interpret() and n % 128 == 0
+
+
+def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
+              ids: jax.Array, start: jax.Array, lead: jax.Array,
+              size: jax.Array, win_lb: jax.Array, suffix: jax.Array, *,
+              kk: int, chunk: int) -> tuple[jax.Array, ...]:
+    """The exact ED span walk of every shard in ``db [S, Tp, n]``, in walk
+    order → ``(topd [S, Q, kk], topi [S, Q, kk], vis [S, Q], work [S, 5])``
+    (arguments and outputs as ``span_scan.span_scan``).  Pallas kernel on
+    TPU (where :func:`span_scan_kernel`); otherwise the XLA ``while_loop``
+    it replaces (``search_device.span_loop``, vmapped over the shards)."""
+    if not span_scan_kernel(db.shape[-1]):
+        from repro.core import search_device as sd
+        from repro.core.metric import ED
+        loop = functools.partial(sd.span_loop, ED, qs, None, k=kk,
+                                 chunk=chunk)
+        topd, topi, vis, _, work = jax.vmap(loop, axis_name=sd.SHARD_AXIS)(
+            db, alive, ids, start, lead, size, win_lb, suffix)
+        return topd, topi, vis, work
+    return _ss.span_scan(qs, db, alive, ids, start, lead, size, win_lb,
+                         suffix, kk=kk, chunk=chunk, interpret=False)
 
 
 def knn_from_leaves(q: jax.Array, db_ordered: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:

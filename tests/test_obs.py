@@ -133,6 +133,9 @@ def test_exact_call_spans_nest_and_share_the_device_timeline(index,
     call = [s for s in got if s.name == "dumpy.exact.call"]
     assert len(call) == 1 and call[0].attrs["Q"] == 8
     assert call[0].attrs["k"] == 5 and call[0].attrs["chunk"] == 128
+    # ED walks its spans in the kernel on a TPU, in the XLA loop elsewhere
+    assert call[0].attrs["scan"] == (
+        "kernel" if sd.ops.span_scan_kernel(64) else "loop")
     kids = sorted((s for s in got if s.parent == call[0].sid),
                   key=lambda s: s.t0)
     assert [s.name for s in kids] == list(EXACT)
@@ -168,13 +171,13 @@ def _replay(index, dev, qs, kk):
     """The span loop of ``_exact_knn_sharded`` on the host, per shard, from
     the program's own schedule: ``(spans walked, live rows, live rows ×
     active queries, spans at which a candidate entered a running top-k,
-    visited [Q])`` summed over shards."""
+    merge rounds, visited [Q])`` summed over shards."""
     sax = index.params.sax
     prep, _ = sd._prep_batch(sd.ED, jax.numpy.asarray(qs), sax.w, sax.b)
     lb_g = np.asarray(sd._interval_lb(dev, prep[0], prep[1],
                                       dev.leaf_lo_g, dev.leaf_hi_g))
     Q = qs.shape[0]
-    walked = rows = pairs = merged = 0
+    walked = rows = pairs = merged = rounds = 0
     vis = np.zeros(Q, np.int64)
     for s in range(dev.db.shape[0]):
         gid = np.asarray(dev.leaf_gid[s])
@@ -202,14 +205,16 @@ def _replay(index, dev, qs, kk):
             slab = db_s[r0:r0 + size[i]]
             d2 = ((qs[:, None, :] - slab[None]) ** 2).sum(-1)
             d2 = np.where(qact[:, None], d2, np.inf)
-            merged += bool((d2 < topd[:, -1:]).any())
+            hits = (d2 < topd[:, -1:]).sum(axis=1)
+            merged += bool(hits.any())
+            rounds += min(int(hits.max()), kk)
             topd = np.sort(np.concatenate([topd, d2], 1), 1)[:, :kk]
             walked += 1
             rows += int(size[i])
             pairs += int(size[i]) * int(qact.sum())
             vis += qact
             i += 1
-    return walked, rows, pairs, merged, vis
+    return walked, rows, pairs, merged, rounds, vis
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -224,10 +229,11 @@ def test_loop_counters_equal_a_host_replay(index, n_shards, k, Q):
     _, cnt = _since(m)
     got = {c.name: c.n for c in cnt}
     assert set(got) == set(sd.WORK_KEYS)
-    walked, rows, pairs, merged, vis = _replay(index, dev, qs, k + 8)
+    walked, rows, pairs, merged, rounds, vis = _replay(index, dev, qs, k + 8)
     assert (got["exact.spans_walked"], got["exact.rows_live"],
-            got["exact.pairs_needed"], got["exact.spans_merged"]) == \
-        (walked, rows, pairs, merged)
+            got["exact.pairs_needed"], got["exact.spans_merged"],
+            got["exact.merge_rounds"]) == \
+        (walked, rows, pairs, merged, rounds)
     np.testing.assert_array_equal(visited, vis)
     real = int((np.asarray(dev.win_size) > 0).sum())
     if k >= 4000:

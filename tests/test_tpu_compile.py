@@ -46,11 +46,27 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _span_scan_case(sds, kk):
+    """The exact walk at the deployment's shapes: one shard of 2^22 rows
+    in 2,048-row spans, a 64-query batch, ``kk`` 18 (plain) or 48 (fuzzy)."""
+    from repro.kernels import span_scan
+    f32, i32 = jnp.float32, jnp.int32
+    S, T, C = 1, 1 << 22, 2048
+    W = T // C
+    return (lambda q, db, al, ids, st, ld, sz, lb, sf: span_scan.span_scan(
+                q, db, al, ids, st, ld, sz, lb, sf, kk=kk, chunk=C),
+            (sds((Q, N), f32), sds((S, T, N), f32), sds((S, T), jnp.bool_),
+             sds((S, T), i32), sds((S, W), i32), sds((S, W), i32),
+             sds((S, W), i32), sds((S, Q, W), f32), sds((S, Q, W + 1), f32)))
+
+
 def _kernel_cases(sds):
     from repro.kernels import dtw_band, lb_isax, lb_keogh, sax_encode
     f32 = jnp.float32
     m = 2048
     return {
+        "span_scan_kk18": _span_scan_case(sds, 18),
+        "span_scan_kk48": _span_scan_case(sds, 48),
         "sax_encode": (lambda x: sax_encode.sax_encode(x, w=W, b=8),
                        (sds((Q, N), f32),)),
         "lb_paa_interval": (
@@ -69,7 +85,8 @@ def _kernel_cases(sds):
 
 
 @pytest.mark.parametrize("kernel", ["sax_encode", "lb_paa_interval",
-                                    "dtw_band", "lb_improved"])
+                                    "dtw_band", "lb_improved",
+                                    "span_scan_kk18", "span_scan_kk48"])
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn, args = _kernel_cases(sds)[kernel]
@@ -81,7 +98,8 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
 def test_sharded_search_compiles_for_four_chips(topo, monkeypatch, program):
     """The sharded search programs on a 4-chip ``data`` mesh, with the
     kernels (not their CPU twins) inside: a Mosaic kernel outside
-    ``shard_map`` in a multi-chip program is refused."""
+    ``shard_map`` in a multi-chip program is refused.  The exact program's
+    span walk is the ``span_scan`` kernel, one call a chip."""
     import numpy as np
     from jax.sharding import Mesh
     from repro.core import distributed as D
@@ -94,5 +112,7 @@ def test_sharded_search_compiles_for_four_chips(topo, monkeypatch, program):
                   n_leaves=64, k=18, q_batch=Q)
     lower = (D.lower_search_sharded if program == "exact"
              else D.lower_search_extended)
-    compiled = lower(mesh, **shapes).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    text = lower(mesh, **shapes).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    if program == "exact":
+        assert "span_scan" in text
