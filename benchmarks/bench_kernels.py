@@ -3,15 +3,29 @@ timing; the derived column carries the analytic TPU-v5e roofline estimate
 for the same shapes)."""
 from __future__ import annotations
 
+import json
+import os
+
 import numpy as np
 import jax.numpy as jnp
 
-from repro.distributed.roofline import HBM_BW, PEAK_FLOPS
 from repro.kernels import ops
 from . import common
 
+PEAKS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "bench", "peaks.json")
+
+
+def v5e_peaks() -> tuple[float, float]:
+    """(HBM bytes/s, bf16 FLOP/s) of one v5e, from the on-chip benchmark's
+    table of published peaks."""
+    with open(PEAKS) as fh:
+        v5e = json.load(fh)["devices"]["TPU v5 lite"]
+    return v5e["hbm_bytes_per_s"], v5e["bf16_flops_per_s"]
+
 
 def run() -> list[tuple[str, float, str]]:
+    hbm_bw, peak_flops = v5e_peaks()
     rng = np.random.default_rng(0)
     rows = []
 
@@ -20,7 +34,7 @@ def run() -> list[tuple[str, float, str]]:
         lambda: tuple(map(lambda a: a.block_until_ready(),
                           ops.sax_encode(x, 16, 8))), repeat=3)
     bytes_moved = x.size * 4 + paa.size * 4 + sax.size * 4
-    est = bytes_moved / HBM_BW * 1e6
+    est = bytes_moved / hbm_bw * 1e6
     rows.append(("kernel/sax_encode/4096x256", dt * 1e6,
                  f"v5e_est_us={est:.2f};mem_bound=True"))
 
@@ -29,7 +43,7 @@ def run() -> list[tuple[str, float, str]]:
     d, dt = common.timed(lambda: ops.pairwise_l2(q, xs).block_until_ready(),
                          repeat=3)
     flops = 2 * 64 * 4096 * 256
-    est = max(flops / PEAK_FLOPS, (q.size + xs.size + d.size) * 4 / HBM_BW) * 1e6
+    est = max(flops / peak_flops, (q.size + xs.size + d.size) * 4 / hbm_bw) * 1e6
     rows.append(("kernel/pairwise_l2/64x4096x256", dt * 1e6,
                  f"v5e_est_us={est:.2f}"))
 
@@ -38,7 +52,7 @@ def run() -> list[tuple[str, float, str]]:
     pq = jnp.asarray(rng.standard_normal((16, 16)), jnp.float32)
     lb, dt = common.timed(
         lambda: ops.lb_isax(pq, lo, hi, 256).block_until_ready(), repeat=3)
-    est = (lo.size * 8 + lb.size * 4) / HBM_BW * 1e6
+    est = (lo.size * 8 + lb.size * 4) / hbm_bw * 1e6
     rows.append(("kernel/lb_isax/16x4096", dt * 1e6,
                  f"v5e_est_us={est:.2f};mem_bound=True"))
     rows.extend(run_device_search())

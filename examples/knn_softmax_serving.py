@@ -1,13 +1,20 @@
-"""Serve a small LM whose softmax is approximated by Dumpy kNN retrieval —
-the paper's motivating application #3 (kNN-softmax [69]).
+"""Decode with a softmax approximated by Dumpy kNN retrieval — the paper's
+motivating application #3 (kNN-softmax [69]).
 
-    PYTHONPATH=src python examples/knn_softmax_serving.py
+The serving half draws each decode step's hidden states near seeded target
+columns of a seeded output embedding and routes them through the
+continuous-batching front-end (``repro.serving.batching``,
+docs/serving.md): each decode row submits as a single request and the
+front-end coalesces them into bucketed device programs.
+
+    PYTHONPATH=src python examples/knn_softmax_serving.py [--batch 4] \
+        [--tokens 24] [--max-wait 0.002]
 """
-import sys
+import argparse
+import time
 
 import numpy as np
 
-from repro.launch import serve
 from repro.serving.knn_softmax import KnnSoftmaxHead
 
 
@@ -28,12 +35,43 @@ def standalone_head_demo() -> None:
           f"(paper §1: ≥80% recall ≈ exact-softmax accuracy)")
 
 
+def frontend_decode_demo(batch: int, tokens: int, max_wait: float) -> None:
+    """Batched decode steps whose retrieval runs through the front-end."""
+    rng = np.random.default_rng(0)
+    d, vocab = 64, 512
+    lm_head = rng.standard_normal((d, vocab)).astype(np.float32) / np.sqrt(d)
+    head = KnnSoftmaxHead(lm_head, th=64, r_candidates=64, nbr_nodes=8)
+    # warms the bucket ladder once; every decode row is then one request
+    frontend = head.make_frontend(max_batch=max(batch, 4), max_wait=max_wait)
+    out_tokens = []
+    t0 = time.time()
+    for _ in range(tokens - 1):
+        tgt = rng.integers(vocab, size=batch)
+        noise = rng.standard_normal((batch, d)).astype(np.float32)
+        H = lm_head[:, tgt].T + 0.3 / np.sqrt(d) * noise
+        out_tokens.append(head.step_batch_via(frontend, H))
+    dt = time.time() - t0
+    frontend.close()
+    print(f"decoded {tokens-1} steps x{batch} in {dt:.2f}s "
+          f"({(tokens-1)*batch/max(dt,1e-9):.1f} tok/s)")
+    s = head.stats
+    print(f"knn-softmax stats: recall@R="
+          f"{s.exact_in_topr/max(s.tokens,1):.2f} "
+          f"argmax-agree={s.agree_argmax/max(s.tokens,1):.2f}")
+    print(f"frontend stats: {frontend.stats.snapshot()}")
+    print("sample:", np.stack(out_tokens, axis=1)[0][:16])
+
+
 def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--tokens", type=int, default=24)
+    ap.add_argument("--max-wait", type=float, default=0.002,
+                    help="front-end coalescing deadline (seconds)")
+    args = ap.parse_args()
     standalone_head_demo()
     print("[serving] batched decode with the Dumpy retrieval head:")
-    sys.argv = [sys.argv[0], "--arch", "olmo-1b", "--preset", "smoke",
-                "--batch", "4", "--tokens", "24", "--knn-softmax"]
-    serve.main()
+    frontend_decode_demo(args.batch, args.tokens, args.max_wait)
 
 
 if __name__ == "__main__":

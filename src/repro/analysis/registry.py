@@ -4,9 +4,9 @@ Each :class:`Entry` names one ``jax.jit`` program the repo ships — the
 sharded exact searches (ED, DTW span, DTW lane), the extended (Alg. 4) and
 approximate descents, the one-shot LB scan, both build-stage programs, and
 the serving head — and knows how to lower it at fixed *audit shapes* on the
-audit mesh.  The recipes are the same ``core.distributed.lower_*`` helpers
-the roofline dry-run uses, so the audited program **is** the production
-program, only smaller.
+audit mesh.  The recipes are the ``core.distributed.lower_*`` helpers, which
+build each program exactly as the serving path does, so the audited program
+**is** the production program, only smaller.
 
 Audit shapes are deliberately modest (64k × 128 collection, batch 8): the
 contract fields the audit checks (collective counts, dtype census, host

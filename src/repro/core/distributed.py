@@ -20,8 +20,9 @@ The paper's Algorithm 1 maps onto the mesh as follows (DESIGN.md §2):
   distances); an all-gather + fused top-k merge (with segment-min dedup over
   original ids) combines them on device — see ``core/search_device.py``.
 
-``build_step`` / ``search_step`` are also exposed for the dry-run so the
-paper's technique itself appears in the §Roofline table.
+``build_step`` / ``search_step`` and the ``lower_*`` helpers below lower
+every program at fixed shapes on a given mesh; the compile-contract audit
+(``repro.analysis.registry``) lowers the shipped programs through them.
 """
 from __future__ import annotations
 
@@ -32,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.sharding import get_mesh, logical_rules, DEFAULT_RULES
+from repro.distributed.sharding import get_mesh
 from .build import DumpyParams
 from .device_index import data_axes
 from .index import DumpyIndex
@@ -40,7 +41,7 @@ from .sax import next_bit_codes_jnp, sax_encode_jnp
 
 
 # ---------------------------------------------------------------------------
-# device programs (jit-able; lowered by the dry-run)
+# device programs (jit-able; lowered by the ``lower_*`` helpers)
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -62,11 +63,11 @@ def search_step(q: jax.Array, db_ordered: jax.Array, leaf_lo: jax.Array,
                 leaf_hi: jax.Array, k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-shot device kNN: LB-scan over the leaf table + exact distances.
 
-    The dry-run lowers this with ``db_ordered`` sharded over ``data`` —
-    GSPMD emits the cross-shard top-k combine.  The third output is the
-    ``[Q]``-shaped per-query min squared lower bound over the leaf table
-    (the pruning statistic; its sqrt lower-bounds each query's true nearest
-    distance)."""
+    ``lower_search_oneshot`` lowers this with ``db_ordered`` sharded over
+    ``data`` — GSPMD emits the cross-shard top-k combine.  The third output
+    is the ``[Q]``-shaped per-query min squared lower bound over the leaf
+    table (the pruning statistic; its sqrt lower-bounds each query's true
+    nearest distance)."""
     from .lb import ed2_batch_jnp, mindist_jnp
     n = db_ordered.shape[1]
     paa_q = q.reshape(q.shape[0], leaf_lo.shape[1], -1).mean(-1)
@@ -155,8 +156,8 @@ def lower_search_sharded(mesh, *, n_series: int = 1 << 22, length: int = 256,
                          q_batch: int = 64, metric=None,
                          shard_health: tuple | None = None):
     """Lower the DeviceIndex sharded windowed search on ``mesh`` with
-    production shardings (shared by both dry-run entry points).  ``metric``
-    (a ``core.metric.Metric``; default ED) selects the specialization —
+    production shardings.  ``metric`` (a ``core.metric.Metric``; default
+    ED) selects the specialization —
     ``Metric("dtw", band)`` lowers the fused masked band-DP program.
     ``shard_health`` lowers the degraded-mode specialization (dead shards
     masked before the all-gather merge).  Returns the jax ``Lowered``
@@ -190,7 +191,7 @@ def lower_search_dtw(mesh, *, n_series: int = 1 << 22, length: int = 256,
                      band: int | None = None, order: str = "shared"):
     """Lower the sharded *DTW* exact search (envelope bounds + the
     LB_Keogh → LB_Improved cascade + fused masked band DP) on ``mesh`` —
-    the ``dumpy_search_dtw`` roofline cell.  DTW now shares the ED-width
+    the ``search_exact_dtw`` audit entries.  DTW now shares the ED-width
     layout (spans sub-block in-program, ``search_device.DTW_SUB``), so the
     span chunk defaults to the same width the ED cell lowers with,
     matching what ``exact_search_device_batch(metric="dtw")`` serves with.
@@ -336,8 +337,8 @@ def lower_search_oneshot(mesh, *, n_series: int = 1 << 22, length: int = 256,
                          w: int = 16, n_leaves: int = 16384, k: int = 50,
                          q_batch: int = 64):
     """Lower the one-shot LB-scan + exact-distance search (``search_step``)
-    with the collection batch-sharded — the ``dumpy_search`` roofline
-    cell."""
+    with the collection batch-sharded — the ``search_oneshot`` audit
+    entry."""
     sh = NamedSharding(mesh, P(data_axes(mesh), None))
     db_abs = jax.ShapeDtypeStruct((n_series, length), jnp.float32)
     q_abs = jax.ShapeDtypeStruct((q_batch, length), jnp.float32)
@@ -350,7 +351,7 @@ def lower_search_oneshot(mesh, *, n_series: int = 1 << 22, length: int = 256,
 def lower_build_step(mesh, *, n_series: int = 1 << 22, length: int = 256,
                      w: int = 16, b: int = 8):
     """Lower Stage 1 + the root histogram (``build_step``) with the
-    collection batch-sharded — the ``dumpy_build`` roofline cell."""
+    collection batch-sharded — the ``build_step`` audit entry."""
     sh = NamedSharding(mesh, P(data_axes(mesh), None))
     db_abs = jax.ShapeDtypeStruct((n_series, length), jnp.float32)
     jitted = jax.jit(build_step, static_argnums=(1, 2), in_shardings=(sh,))
@@ -367,43 +368,3 @@ def lower_build_bottomup(mesh, *, n_series: int = 1 << 22, w: int = 16,
 
     sax_abs = jax.ShapeDtypeStruct((n_series, w), jnp.uint8)
     return jax.jit(lambda s: _lexsort_words(s, w, b)).lower(sax_abs)
-
-
-def dryrun_cells(mesh) -> dict:
-    """Extra §Roofline cells for the paper's own technique: lower+compile the
-    distributed build step (Stage 1 and the bottom-up grouping program), the
-    one-shot search, the DeviceIndex sharded windowed search, the sharded
-    extended (Alg. 4) search, the batched approximate descent and the
-    serving-head retrieval program on the production mesh."""
-    out = {}
-    w = 16
-    n_series, length = 1 << 20, 256            # 1M × 256 per-cell stand-in
-    with logical_rules(mesh, DEFAULT_RULES):
-        out["dumpy_build"] = lower_build_step(
-            mesh, n_series=n_series, length=length, w=w).compile()
-        out["dumpy_build_bottomup"] = lower_build_bottomup(
-            mesh, n_series=n_series, w=w).compile()
-
-        L = 4096
-        out["dumpy_search"] = lower_search_oneshot(
-            mesh, n_series=n_series, length=length, w=w, n_leaves=L,
-            k=50).compile()
-
-        lo3 = lower_search_sharded(mesh, n_series=n_series, length=length,
-                                   w=w, chunk=4096, n_leaves=L)
-        out["dumpy_search_sharded"] = lo3.compile()
-
-        lo4 = lower_search_extended(mesh, n_series=n_series, length=length,
-                                    w=w, chunk=4096, n_leaves=L)
-        out["dumpy_search_extended"] = lo4.compile()
-
-        lo5 = lower_search_dtw(mesh, n_series=n_series, length=length,
-                               w=w, n_leaves=L)
-        out["dumpy_search_dtw"] = lo5.compile()
-
-        lo6 = lower_search_approx(mesh, n_series=n_series, length=length,
-                                  w=w, chunk=4096, n_leaves=L)
-        out["dumpy_search_approx"] = lo6.compile()
-
-        out["dumpy_serving_head"] = lower_serving_head(mesh).compile()
-    return out

@@ -1,9 +1,9 @@
-"""Logical-axis sharding rules (MaxText-style).
+"""Meshes and logical-axis sharding rules (MaxText-style).
 
-Model code annotates tensors with *logical* axis names; a rules table maps
-them to mesh axes per run mode.  The same model definition then runs on a
-single CPU device (rules empty → no-op), the 256-chip pod, or the 512-chip
-multi-pod mesh without modification.
+``make_mesh`` builds the meshes the sharded search runs on, and
+``logical_rules`` installs the active mesh that ``core.distributed`` reads
+through ``get_mesh``.  ``shard`` annotates a tensor with *logical* axis
+names, which a rules table maps to mesh axes (rules empty → no-op).
 
 Conventions:
   batch        — global batch               → ("pod", "data")
@@ -138,70 +138,3 @@ def shard(x: jax.Array, *names: str | None) -> jax.Array:
         fixed.append(ax if x.shape[i] % size == 0 else None)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*fixed)))
-
-
-def named_sharding(names: tuple[str | None, ...]) -> NamedSharding | None:
-    mesh, rules = get_mesh(), get_rules()
-    if mesh is None or rules is None:
-        return None
-    return NamedSharding(mesh, _resolve(names, rules, mesh))
-
-
-def tree_shardings(logical_tree: Any) -> Any:
-    """Map a pytree of logical-name tuples to NamedShardings (dry-run
-    in_shardings).  Leaves are tuples of str/None."""
-    mesh, rules = get_mesh(), get_rules()
-    assert mesh is not None and rules is not None
-
-    def leaf(names):
-        return NamedSharding(mesh, _resolve(tuple(names), rules, mesh))
-
-    return jax.tree.map(leaf, logical_tree,
-                        is_leaf=lambda x: isinstance(x, tuple) and
-                        all(isinstance(i, (str, type(None))) for i in x))
-
-
-def shardings_for(abstract_tree: Any, logical_tree: Any) -> Any:
-    """Like ``tree_shardings`` but validated against the abstract leaves:
-    mesh axes whose size does not divide the tensor dim are dropped for that
-    dim (jit ``in_shardings`` requires exact divisibility — e.g. whisper's
-    51865 vocab cannot shard 16 ways and falls back to replication)."""
-    mesh, rules = get_mesh(), get_rules()
-    assert mesh is not None and rules is not None
-
-    def leaf(abs_leaf, names):
-        spec = _resolve(tuple(names), rules, mesh)
-        fixed = []
-        for i, ax in enumerate(spec):
-            if ax is None or i >= len(abs_leaf.shape):
-                fixed.append(None)
-                continue
-            axes = (ax,) if isinstance(ax, str) else tuple(ax)
-            size = 1
-            for a in axes:
-                size *= mesh.shape[a]
-            fixed.append(ax if abs_leaf.shape[i] % size == 0 else None)
-        return NamedSharding(mesh, P(*fixed))
-
-    is_names = lambda x: isinstance(x, tuple) and all(
-        isinstance(i, (str, type(None))) for i in x)
-    return jax.tree.map(leaf, abstract_tree, logical_tree,
-                        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
-                        )
-
-
-def divisible(dim: int, names: tuple[str | None, ...], axis_index: int) -> bool:
-    """Check a tensor dim divides the mapped mesh axes (used by configs to
-    drop illegal shardings, e.g. 8 kv heads over a 16-way model axis)."""
-    mesh, rules = get_mesh(), get_rules()
-    if mesh is None or rules is None:
-        return True
-    spec = _resolve(names, rules, mesh)
-    ax = spec[axis_index] if axis_index < len(spec) else None
-    if ax is None:
-        return True
-    axes = (ax,) if isinstance(ax, str) else ax
-    size = 1
-    for a in axes:
-        size *= mesh.shape[a]
-    return dim % size == 0

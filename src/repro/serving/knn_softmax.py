@@ -224,11 +224,10 @@ class KnnSoftmaxHead:
         """Batched decode step routed through a coalescing front-end.
 
         Hidden states validate **once** (the vectorized check inside
-        :meth:`_encode_queries`) instead of once per row like the old
-        ``serve.py`` host loop; each encoded row then submits as a single
-        request, so independent decode streams sharing one front-end
-        coalesce into common buckets.  Token selection and recall stats are
-        those of :meth:`step_batch`."""
+        :meth:`_encode_queries`) instead of once per row; each encoded row
+        then submits as a single request, so independent decode streams
+        sharing one front-end coalesce into common buckets.  Token selection
+        and recall stats are those of :meth:`step_batch`."""
         H = np.atleast_2d(np.asarray(H, np.float32))
         qs = self._encode_queries(H)     # one vectorized validation per batch
         met = "dtw" if self.metric.is_dtw else "ed"

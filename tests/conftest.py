@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests must see exactly 1 device (the dry-run sets its own 512-device flag
-# in a subprocess).  Keep XLA on a deterministic single-threaded-ish setup.
+# Tests must see exactly 1 device (multi-device tests force their own device
+# count in a subprocess).  Keep XLA on a deterministic single-threaded-ish setup.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
