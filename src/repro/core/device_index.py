@@ -11,8 +11,8 @@ search impossible to express.  ``DeviceIndex`` unifies it:
   ``[S, Tp, n]`` *leaf-aligned* shard layout: leaves are partitioned into
   ``S`` contiguous groups cut only at leaf boundaries (so every leaf pack
   stays contiguous inside one shard) and each shard is padded to the common
-  row count ``Tp``, a multiple of 8 (pad rows: ``alive=False``, ``id=-1``,
-  zero series);
+  row count ``Tp``, a multiple of 128 (pad rows: ``alive=False``,
+  ``id=-1``, zero series);
 * each shard's local-to-global leaf id table and its fixed-size span
   schedule (windows + (leaf, window)-intersection edges) are precomputed
   so each shard can run the windowed-pruning loop locally on the leaf
@@ -20,8 +20,9 @@ search impossible to express.  ``DeviceIndex`` unifies it:
   both metrics (the interval MINDIST of ``core.metric`` compares it
   against the query PAA for ED and against the query's LB_Keogh envelope
   summary for DTW, so no DTW-specific leaf state is uploaded); the span
-  size is a multiple of 8 rows, so every span starts on an 8-row tile and
-  the span-scan kernel can copy it straight from HBM;
+  size is a multiple of 128 rows, so every span starts on a 128-row tile
+  and the span-scan kernel can copy its rows, and its ids as a lane slice,
+  straight from HBM;
 * the global leaf table (``leaf_start/size`` in flattened ``S·Tp`` row
   coordinates, global lo/hi envelopes) and the flattened routing tables
   serve the batched approximate descent; the sibling routing tables
@@ -421,9 +422,10 @@ jax.tree_util.register_pytree_node(DeviceIndex, _flatten, _unflatten)
 
 
 def _round_rows(rows: int) -> int:
-    """``rows`` rounded up to a whole 8-row tile (at least one): shard and
-    span sizes, so that every span starts on a tile."""
-    return max(-(-int(rows) // 8) * 8, 8)
+    """``rows`` rounded up to a whole 128-row tile (at least one): shard
+    and span sizes, so that every span starts on a tile, where the span
+    scan's copy of the span's ids (a lane slice) can start."""
+    return max(-(-int(rows) // 128) * 128, 128)
 
 
 def abstract_device_index(n_series: int, length: int, w: int, *,

@@ -8,19 +8,20 @@ a sequential one, so the running top-k stays resident in the output blocks
 from the first span to the last.
 
 * **Slab streaming.**  ``db`` stays in HBM.  The walk-ordered span starts
-  arrive by scalar prefetch; span i+1's ``[chunk, n]`` rows are copied into
-  the second of two VMEM slab buffers while span i is scored.  Span starts
-  lie on 8-row tiles (``DeviceIndex``), as the copy needs.  The copy of
+  arrive by scalar prefetch; span i+1's ``[chunk, n]`` rows, and its
+  ``chunk`` ids from the ``[S, 1, Tp]`` id row, are copied into the second
+  of two VMEM buffers while span i is scored.  Span starts lie on 128-row
+  tiles (``DeviceIndex``), as the ids' lane slice needs.  The copy of
   span i+1 is issued only if some query's suffix bound there lies below its
   running ``kk``-th best before span i merges, a condition that merging can
   only falsify; once the walk's condition fails, no copy is issued and the
   remaining grid steps do nothing.
 * **Distance.**  ``max(|q|² + |x|² − 2 q·x, 0)`` as ``ed2_batch_jnp``: the
   product at ``Precision.HIGHEST``, ``|x|²`` summed from the VMEM slab.
-* **Masking.**  A row scores if it lies inside the span's live window and
-  is alive (both folded into a walk-ordered id table, ``-1`` elsewhere,
-  read in blocks of 8 spans); a query scores if its span bound lies below
-  its running ``kk``-th best.
+* **Masking.**  A row scores if it is alive (its copied id is not ``-1``)
+  and lies inside the span's live window, ``lead <= col < lead + size`` by
+  scalar prefetch; a query scores if its span bound lies below its running
+  ``kk``-th best.
   Everything else is ``+inf``.
 * **Merge.**  ``m = min(max_q #{key < cut}, kk)`` insertion rounds, the
   rounds of ``ops.topk_merge_cutoff``: each takes every query's least
@@ -67,18 +68,30 @@ def _column(blk: jax.Array, c, fill) -> jax.Array:
     return jnp.min(jnp.where(lane == c, blk, fill), axis=1, keepdims=True)
 
 
-def _kernel(start_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref, nx_ref,
-            id_ref, db_ref, topd_ref, topi_ref, vis_ref, work_ref,
-            buf, sem, key_ref, st_ref, *, kk: int, chunk: int, W: int):
+def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
+            nx_ref, id_ref, db_ref, topd_ref, topi_ref, vis_ref, work_ref,
+            buf, sem, idb, id_sem, key_ref, st_ref, *, kk: int, chunk: int,
+            W: int):
     s, i = pl.program_id(0), pl.program_id(1)
     Q, KP = topd_ref.shape
     C = chunk
     slot = i % 2
 
-    def copy(span, into):
-        r0 = pl.multiple_of(start_ref[s * W + span], 8)
-        return pltpu.make_async_copy(db_ref.at[s, pl.ds(r0, C), :],
-                                     buf.at[into], sem.at[into])
+    def copies(span, into):
+        """The span's rows and its ids, one DMA each."""
+        r0 = pl.multiple_of(start_ref[s * W + span], 128)
+        return (pltpu.make_async_copy(db_ref.at[s, pl.ds(r0, C), :],
+                                      buf.at[into], sem.at[into]),
+                pltpu.make_async_copy(id_ref.at[s, :, pl.ds(r0, C)],
+                                      idb.at[into], id_sem.at[into]))
+
+    def start(span, into):
+        for c in copies(span, into):
+            c.start()
+
+    def wait(span, into):
+        for c in copies(span, into):
+            c.wait()
 
     @pl.when(i == 0)
     def _init():
@@ -98,14 +111,14 @@ def _kernel(start_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref, nx_ref,
     def _walk():
         @pl.when(st_ref[slot] == 0)
         def _first():
-            copy(i, slot).start()
+            start(i, slot)
 
         @pl.when(nxt)
         def _prefetch():
-            copy(i + 1, 1 - slot).start()
+            start(i + 1, 1 - slot)
             st_ref[1 - slot] = 1
 
-        copy(i, slot).wait()
+        wait(i, slot)
         st_ref[slot] = 0
 
         slab = buf[slot]                                         # [C, n]
@@ -118,9 +131,10 @@ def _kernel(start_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref, nx_ref,
             preferred_element_type=jnp.float32)                  # [Q, C]
         xn = jnp.sum(slab * slab, axis=1)[None, :]               # [1, C]
         d2 = jnp.maximum((qn_ref[...] + xn) - 2.0 * cross, 0.0)
-        sub = jax.lax.broadcasted_iota(jnp.int32, (8, C), 0)
-        ids = jnp.max(jnp.where(sub == i % 8, id_ref[...], -1), axis=0,
-                      keepdims=True)                             # [1, C]
+        lead, size = lead_ref[s * W + i], size_ref[s * W + i]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+        ids = jnp.where((lane >= lead) & (lane < lead + size), idb[slot],
+                        -1)                                      # [1, C]
         qact = lb < cut                                          # [Q, 1]
         dk = _key(jnp.where((ids >= 0) & qact, d2, jnp.inf))
         cutk = _key(cut)
@@ -160,7 +174,6 @@ def _kernel(start_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref, nx_ref,
                            jnp.full((Q, 1), -1, jnp.int32)))
         act = qact.astype(jnp.int32)
         vis_ref[...] += act
-        size = size_ref[s * W + i]
         st_ref[2] += 1
         st_ref[3] += size
         st_ref[4] += size * jnp.sum(act)
@@ -169,7 +182,7 @@ def _kernel(start_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref, nx_ref,
 
     @pl.when(jnp.logical_not(go) & (st_ref[slot] == 1))
     def _drain():
-        copy(i, slot).wait()
+        wait(i, slot)
         st_ref[slot] = 0
 
     @pl.when(i == W - 1)
@@ -198,7 +211,8 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
     """Walk every shard's span schedule, in walk order, for ``qs [Q, n]``.
 
     ``db [S, Tp, n]``, ``alive/ids [S, Tp]``; ``start/lead/size [S, W]``
-    the walk-ordered schedule (starts multiples of 8 rows); ``win_lb [S, Q,
+    the walk-ordered schedule (``Tp``, ``chunk`` and the starts multiples
+    of 128 rows, as ``DeviceIndex`` lays them out); ``win_lb [S, Q,
     W]`` the span bounds in walk order and ``suffix [S, Q, W+1]`` their
     suffix minima with a ``+inf`` column appended.  Returns ``(topd [S, Q,
     kk], topi [S, Q, kk], vis [S, Q], work [S, 5])``.  A batch whose
@@ -221,15 +235,10 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
     KP = -(-kk // 128) * 128
     NB = -(-W // 128)
 
-    W8 = -(-W // 8) * 8
-    idv = jnp.where(alive, ids, -1)
-    rows = jax.vmap(jax.vmap(
-        lambda v, st: jax.lax.dynamic_slice(v, (st,), (C,)),
-        in_axes=(None, 0)))(idv, start)                         # [S, W, C]
-    j = jnp.arange(C, dtype=jnp.int32)
-    inside = (j >= lead[..., None]) & (j < (lead + size)[..., None])
-    idw = jnp.pad(jnp.where(inside, rows, -1), ((0, 0), (0, W8 - W), (0, 0)),
-                  constant_values=-1)
+    if Tp % 128 or C % 128:
+        raise ValueError(f"span_scan needs 128-row tiles, got Tp={Tp}, "
+                         f"chunk={C}")
+    idv = jnp.where(alive, ids, -1)[:, None, :]                 # [S, 1, Tp]
     qs = qs.astype(jnp.float32)
     qn = (qs * qs).sum(axis=-1, keepdims=True)                   # [Q, 1]
     lb = _blocks(win_lb, NB)
@@ -246,13 +255,13 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
     topd, topi, vis, work = pl.pallas_call(
         functools.partial(_kernel, kk=kk, chunk=C, W=W),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(S, W),
             in_specs=[
                 pl.BlockSpec((Q, n), lambda s, i, *_: (0, 0)),
                 pl.BlockSpec((Q, 1), lambda s, i, *_: (0, 0)),
                 table, table, table,
-                pl.BlockSpec((None, 8, C), lambda s, i, *_: (s, i // 8, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
@@ -262,6 +271,8 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
             ],
             scratch_shapes=[
                 pltpu.VMEM((2, C, n), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, 1, C), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((Q, C), jnp.int32),
                 pltpu.SMEM((8,), jnp.int32),
@@ -277,6 +288,7 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
             vmem_limit_bytes=min(vmem, 100 << 20)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="span_scan",
-    )(start.reshape(-1), size.reshape(-1), qs, qn, lb, sf, nx, idw, db)
+    )(start.reshape(-1), lead.reshape(-1), size.reshape(-1), qs, qn, lb,
+      sf, nx, idv, db)
     return (topd[:, :, :kk], topi[:, :, :kk], vis[:, :, 0],
             work[:, 0, :5])

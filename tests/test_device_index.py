@@ -66,6 +66,24 @@ def test_leaf_aligned_shard_layout(built):
     assert sizes.max() - sizes.min() <= 2 * dev.lmax
 
 
+@pytest.mark.parametrize("n_shards", [1, 2, 3])
+def test_spans_start_on_128_row_tiles(n_shards):
+    """Shard row counts off the tile are padded to whole 128-row tiles, so
+    every span start (the end-clamped last one too) is a multiple of 128,
+    as the span scan's copy of a span's ids needs; pad rows are dead."""
+    idx = DumpyIndex.build(random_walks(2999, 64, seed=9), PARAMS)
+    dev = DeviceIndex.from_index(idx, chunk=384, n_shards=n_shards)
+    Ts = np.diff(dev.row_bounds)
+    assert (Ts % 2 == 1).any() and (Ts % 128 != 0).any()
+    Tp = dev.db.shape[1]
+    assert Tp % 128 == 0 and dev.chunk == 384
+    assert (np.asarray(dev.win_start) % 128 == 0).all()
+    ids, alive = np.asarray(dev.ids), np.asarray(dev.alive)
+    for s, t in enumerate(Ts):
+        assert (ids[s, t:] == -1).all() and not alive[s, t:].any()
+        assert (ids[s, :t] >= 0).all()
+
+
 def test_inverse_order_maps_ids_to_their_rows(built):
     db, idx = built
     dev = idx.device_index()

@@ -74,6 +74,10 @@ CASES = {
     "clamped_lead": (7, 18, False, True, 1, 640),
     "two_shards": (7, 18, False, True, 2, 256),
     "q1_stops_early": (1, 18, False, False, 1, 128),
+    # shard row counts off the 128-row tile: the layout rounds them up, so
+    # every span, the end-clamped last one too, starts on a tile
+    "fuzzy_two_shards_c384": (7, 18, True, True, 2, 384),
+    "fuzzy_three_shards_c384": (64, 48, True, True, 3, 384),
 }
 
 
@@ -90,13 +94,28 @@ def test_kernel_walks_as_the_loop(case):
     want = (want[0], want[1], want[2], want[4])
     got = _kernel(*args, kk=kk, chunk=dev.chunk)
     _close(got[0], want[0], qs, dev)
-    for g, w in zip(got[1:], want[1:]):
+    for g, w in zip(got[1:3], want[1:3]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    work = dict(zip(sd.WORK_KEYS, np.asarray(got[3]).sum(axis=0)))
+    g_work, w_work = np.asarray(got[3]), np.asarray(want[3])
+    if fuzzy and S > 1:
+        # merge rounds count candidates below a query's cut, and the two
+        # sum d² in their own orders (``_close``): where replicas of one
+        # series sit at the cut, a span can take a round more or fewer.
+        # The walk itself (spans, rows, pairs, spans merged) is exact.
+        np.testing.assert_array_equal(g_work[:, :4], w_work[:, :4])
+        assert (np.abs(g_work[:, 4] - w_work[:, 4]) <= g_work[:, 3]).all()
+    else:
+        np.testing.assert_array_equal(g_work, w_work)
+    work = dict(zip(sd.WORK_KEYS, g_work.sum(axis=0)))
     real = int((np.asarray(dev.win_size) > 0).sum())
     assert work["exact.merge_rounds"] >= work["exact.spans_merged"] > 0
     if case == "clamped_lead":
         assert (np.asarray(dev.win_lead) > 0).any()
+    if case.endswith("_c384"):
+        assert (np.diff(dev.row_bounds) % 128 != 0).all()
+        assert (np.asarray(dev.win_start) % 128 == 0).all()
+        if S == 3:
+            assert (np.asarray(dev.win_lead)[:, -1] > 0).all()
     if case == "q1_stops_early":
         assert work["exact.spans_walked"] < real
     if case == "two_shards":

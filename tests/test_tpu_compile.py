@@ -99,7 +99,11 @@ def test_sharded_search_compiles_for_four_chips(topo, monkeypatch, program):
     """The sharded search programs on a 4-chip ``data`` mesh, with the
     kernels (not their CPU twins) inside: a Mosaic kernel outside
     ``shard_map`` in a multi-chip program is refused.  The exact program's
-    span walk is the ``span_scan`` kernel, one call a chip."""
+    span walk is the ``span_scan`` kernel, one call a chip, which copies
+    each span's ids with its rows: no ``while`` op (such as a loop that
+    gathers a walk-ordered id table) runs outside it."""
+    import re
+
     import numpy as np
     from jax.sharding import Mesh
     from repro.core import distributed as D
@@ -116,3 +120,7 @@ def test_sharded_search_compiles_for_four_chips(topo, monkeypatch, program):
     assert 'custom_call_target="tpu_custom_call"' in text
     if program == "exact":
         assert "span_scan" in text
+        loops = [line for line in text.splitlines()
+                 if re.search(r"\swhile\(", line)
+                 and "custom-call" not in line]
+        assert not loops, loops
