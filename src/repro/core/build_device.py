@@ -18,7 +18,11 @@ map as:
   4. pack         — ``pack_siblings`` (shared with the host backend verbatim)
   5. materialize  — one device ``lexsort`` by (leaf-atom rank, row id) emits
                     the leaf-contiguous order; ``db_ordered`` is a device
-                    gather, never round-tripped through the host
+                    gather, never round-tripped through the host.  On a
+                    mesh the collection never goes whole to one device:
+                    each shard's rows, cut where ``DeviceIndex`` cuts them,
+                    are gathered on the host and copied to the device that
+                    owns the shard (``device_index.place_shards``)
 
 The result layout equals the host build's up to the tie-breaking documented
 in ``docs/build_pipeline.md``: leaf membership, leaf order, CSR offsets and
@@ -41,22 +45,41 @@ from . import fuzzy as fuzzy_mod
 from .build import (BuildStats, DumpyParams, TreeNode, children_isax,
                     collect_leaves, finalize_stats, pack_siblings,
                     partition_by_sid, plan_node_grouped)
+from .device_index import place_shards
 from .index import FlatLeaves, flatten_tree
 from .lb import node_bounds_np
 from .sax import next_bits_np, pack_bits_np, sax_encode_jnp, sax_encode_np
 
 
+#: rows the host encoder takes at a time: bounds its float64 staging
+ENCODE_ROWS = 1 << 16
+
+
 @dataclasses.dataclass
 class DeviceBuildResult:
     """Everything ``DumpyIndex`` needs, plus the device-resident ordered
-    collection so ``DeviceIndex`` can be assembled without a host copy."""
+    collection so ``DeviceIndex`` can be assembled without a host copy:
+    ``db_ordered_dev`` on one device, or ``db_shards`` on a mesh."""
     root: TreeNode
     stats: BuildStats
     paa: np.ndarray            # [N, w] float32
     sax: np.ndarray            # [N, w] uint8
     flat: FlatLeaves
     order: np.ndarray          # [total] int64 (= flat.order)
-    db_ordered_dev: jax.Array  # [total, n] float32, on device
+    db_ordered_dev: jax.Array | None  # [total, n] float32, on device
+    db_shards: dict | None            # mesh: {device: [1, Tp, n] float32}
+
+
+def _encode_np(db: np.ndarray, p) -> tuple[np.ndarray, np.ndarray]:
+    """``sax_encode_np`` of ``db`` in blocks of :data:`ENCODE_ROWS` rows:
+    each row's summary is computed alone, so the result is bitwise the
+    whole call's, without a float64 copy of the whole collection."""
+    paa = np.empty((db.shape[0], p.w), np.float32)
+    sax = np.empty((db.shape[0], p.w), np.uint8)
+    for r in range(0, db.shape[0], ENCODE_ROWS):
+        paa[r:r + ENCODE_ROWS], sax[r:r + ENCODE_ROWS] = sax_encode_np(
+            db[r:r + ENCODE_ROWS], p)
+    return paa, sax
 
 
 @functools.partial(jax.jit, static_argnames=("w", "b"))
@@ -91,14 +114,18 @@ def _lexsort_words(sax: jax.Array, w: int, b: int):
 
 def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
                  encoder: str = "np",
-                 precomputed: tuple[np.ndarray, np.ndarray] | None = None
-                 ) -> DeviceBuildResult:
+                 precomputed: tuple[np.ndarray, np.ndarray] | None = None,
+                 mesh=None) -> DeviceBuildResult:
     """Bottom-up build over grouped SAX words (Algorithm 1 on the device).
 
     ``encoder`` — ``"np"`` (default; bitwise-identical summaries to the host
     backend, required for exact layout parity), ``"jnp"`` or ``"pallas"``
     (device PAA in float32 — borderline symbols may differ from the host
     encoder by one breakpoint, see docs/build_pipeline.md).
+
+    ``mesh`` — lay the ordered rows out as the shards of a ``DeviceIndex``
+    on it (``db_shards``): the collection never goes whole to one device,
+    so only the host encoder applies.  The layout itself is the same.
 
     Stage 1 is the span ``dumpy.build.encode``, stages 2–4 (with fuzzy
     duplication) ``dumpy.build.split``, stage 5 ``dumpy.build.layout``,
@@ -109,14 +136,17 @@ def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
     n = db.shape[0]
     w, b = p.sax.w, p.sax.b
     p.sax.validate_series_length(db.shape[-1])
-    db_dev = jnp.asarray(db)
+    if mesh is not None and encoder != "np" and precomputed is None:
+        raise ValueError(f"encoder {encoder!r} needs the collection on one "
+                         "device; a mesh build encodes on the host")
+    db_dev = jnp.asarray(db) if mesh is None else None
 
     # -- Stage 1: encode ----------------------------------------------------
     with obs.span("dumpy.build.encode"):
         if precomputed is not None:
             paa, sax = precomputed
         elif encoder == "np":
-            paa, sax = sax_encode_np(db, p.sax)
+            paa, sax = _encode_np(db, p.sax)
         elif encoder == "jnp":
             paa_j, sax_j = sax_encode_jnp(db_dev, w, b)
             paa = np.asarray(paa_j, np.float32)
@@ -136,8 +166,10 @@ def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
         root.series_ids = np.arange(n, dtype=np.int64)
         finalize_stats(root, stats, p.th)
         flat = flatten_tree(root, b)
+        shards = (None if mesh is None else
+                  place_shards(db, flat.order, flat.leaf_offsets, mesh))
         return DeviceBuildResult(root, stats, paa, sax, flat,
-                                 flat.order, db_dev)
+                                 flat.order, db_dev, shards)
 
     # -- Stage 2: group by SAX word ----------------------------------------
     with obs.span("dumpy.build.split"):
@@ -288,7 +320,9 @@ def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
                     parts.append(ex)
             order = (np.concatenate(parts) if parts else no_rows)
             order_dev = jnp.asarray(order, dtype=jnp.int32)
-        db_ordered_dev = jnp.take(db_dev, order_dev, axis=0)
+        db_ordered_dev = shards = None
+        if mesh is None:
+            db_ordered_dev = jnp.take(db_dev, order_dev, axis=0)
 
         sym = np.zeros((L, w), np.int16)
         card = np.zeros((L, w), np.uint8)
@@ -303,6 +337,9 @@ def device_build(db: np.ndarray, params: DumpyParams | None = None, *,
             leaf.series_ids = order[offsets[i]:offsets[i + 1]].copy()
 
         finalize_stats(root, stats, p.th)
-        jax.block_until_ready(db_ordered_dev)
+        if mesh is None:
+            jax.block_until_ready(db_ordered_dev)
+        else:
+            shards = place_shards(db, order, offsets, mesh)
     return DeviceBuildResult(root, stats, paa, sax, flat, order,
-                             db_ordered_dev)
+                             db_ordered_dev, shards)

@@ -171,18 +171,24 @@ class DeviceIndex:
     # -- construction --------------------------------------------------------
     @classmethod
     def from_index(cls, index: "DumpyIndex", chunk: int = 2048,
-                   n_shards: int = 1, *, db_device=None,
+                   n_shards: int = 1, *, db_device=None, db_shards=None,
                    mesh=None) -> "DeviceIndex":
         """Build the full device state from a host ``DumpyIndex``.
 
         ``n_shards`` fixes the leading axis; the shard boundaries are the
-        leaf boundaries nearest the ideal ``total/S`` cuts, so a leaf never
-        straddles two shards and the span loop needs no cross-shard windows.
+        leaf boundaries nearest the ideal ``total/S`` cuts (:func:`leaf_cut`),
+        so a leaf never straddles two shards and the span loop needs no
+        cross-shard windows.
 
         ``db_device`` — optional device-resident ``[total, n]`` array already
         in leaf-contiguous order (the device build's gather output): the data
         plane is then assembled on device and the host ``db_ordered``
         permutation is never materialized.
+
+        ``db_shards`` — with ``mesh``: the mesh build's rows, ``{device:
+        [1, Tp, n]}``, each shard already on the device that owns it
+        (:func:`place_shards`); they become the slab as they are, with no
+        copy.
 
         ``mesh`` — place the result on it (:meth:`shard`).  The ``[S, Tp,
         n]`` slab goes shard by shard straight to the devices that own each
@@ -196,25 +202,14 @@ class DeviceIndex:
         w = flat.leaf_lo.shape[1]
         S = max(int(n_shards), 1)
 
-        # leaf-aligned cuts: the leaf boundary nearest each ideal row split
-        cut_leaf = [0]
-        for s in range(1, S):
-            ideal = s * total / S
-            j = int(np.searchsorted(offs, ideal))
-            if j > 0 and (j > L or ideal - float(offs[j - 1])
-                          < float(offs[j]) - ideal):
-                j -= 1
-            cut_leaf.append(min(max(j, cut_leaf[-1]), L))
-        cut_leaf.append(L)
-        row_bounds = tuple(int(offs[c]) for c in cut_leaf)
-
-        Tp = _round_rows(max(row_bounds[s + 1] - row_bounds[s]
-                         for s in range(S)))
+        cut_leaf, row_bounds = leaf_cut(offs, S)
+        Tp = shard_rows(row_bounds)
         chunk_eff = min(_round_rows(chunk), Tp)
         W = math.ceil(Tp / chunk_eff)
         Lp = max(cut_leaf[s + 1] - cut_leaf[s] for s in range(S)) + 1  # +pad
 
-        db_sh = np.zeros((S, Tp, n), np.float32)
+        host_rows = db_device is None and db_shards is None
+        db_sh = np.zeros((S, Tp, n), np.float32) if host_rows else None
         alive_sh = np.zeros((S, Tp), bool)
         ids_sh = np.full((S, Tp), -1, np.int32)
         gid_sh = np.full((S, Lp), -1, np.int32)
@@ -230,7 +225,7 @@ class DeviceIndex:
             r0, r1 = row_bounds[s], row_bounds[s + 1]
             l0, l1 = cut_leaf[s], cut_leaf[s + 1]
             Ts = r1 - r0
-            if db_device is None:
+            if host_rows:
                 db_sh[s, :Ts] = index.db_ordered[r0:r1]
             alive_sh[s, :Ts] = alive_ord[r0:r1]
             ids_sh[s, :Ts] = order[r0:r1]
@@ -287,7 +282,15 @@ class DeviceIndex:
         db_sharding = None
         if mesh is not None:
             db_sharding = NamedSharding(mesh, P(data_axes(mesh), None, None))
-        if db_device is None:
+        if db_shards is not None:
+            # the mesh build laid each shard out on its device at these cuts
+            if db_sharding is None or len(db_shards) != S or any(
+                    a.shape != (1, Tp, n) for a in db_shards.values()):
+                raise ValueError("db_shards does not match this mesh's "
+                                 f"{S} shards of {Tp} rows")
+            db_j = jax.make_array_from_single_device_arrays(
+                (S, Tp, n), db_sharding, list(db_shards.values()))
+        elif db_device is None:
             db_j = (jnp.asarray(db_sh) if db_sharding is None
                     else jax.device_put(db_sh, db_sharding))
         elif db_sharding is None:
@@ -419,6 +422,71 @@ def _unflatten(aux, children) -> DeviceIndex:
 
 
 jax.tree_util.register_pytree_node(DeviceIndex, _flatten, _unflatten)
+
+
+def mesh_shards(mesh) -> int:
+    """Shards of an index placed on ``mesh``: the size of its
+    :func:`data_axes` (1 without a mesh)."""
+    s = 1
+    for ax in ("pod", "data"):
+        if mesh is not None and ax in mesh.axis_names:
+            s *= mesh.shape[ax]
+    return s
+
+
+def leaf_cut(offs: np.ndarray, n_shards: int) -> tuple[tuple, tuple]:
+    """``(leaf_bounds, row_bounds)``: where the leaf-ordered rows (leaf
+    offsets ``offs [L+1]``) are cut into ``n_shards`` shards.  Each cut is
+    the leaf boundary nearest an ideal ``total/S`` row split, so a leaf
+    never straddles two shards.  ``DeviceIndex.from_index`` and the mesh
+    build's layout (:func:`place_shards`) cut at these same rows."""
+    offs = np.asarray(offs, np.int64)
+    L = len(offs) - 1
+    total = int(offs[-1])
+    S = max(int(n_shards), 1)
+    cut_leaf = [0]
+    for s in range(1, S):
+        ideal = s * total / S
+        j = int(np.searchsorted(offs, ideal))
+        if j > 0 and (j > L or ideal - float(offs[j - 1])
+                      < float(offs[j]) - ideal):
+            j -= 1
+        cut_leaf.append(min(max(j, cut_leaf[-1]), L))
+    cut_leaf.append(L)
+    return (tuple(int(c) for c in cut_leaf),
+            tuple(int(offs[c]) for c in cut_leaf))
+
+
+def shard_rows(row_bounds: tuple) -> int:
+    """``Tp``: the rows of every shard, its largest share of ``row_bounds``
+    rounded up to a 128-row tile."""
+    return _round_rows(max(row_bounds[s + 1] - row_bounds[s]
+                           for s in range(len(row_bounds) - 1)))
+
+
+def place_shards(db: np.ndarray, order: np.ndarray, offs: np.ndarray,
+                 mesh) -> dict:
+    """The mesh build's layout: ``{device: [1, Tp, n]}``, each shard's
+    leaf-ordered rows ``db[order[r0:r1]]`` cut at :func:`leaf_cut` and
+    zero-padded to ``Tp`` on the host, then copied to the device that owns
+    the shard while the next one is gathered.  No device ever holds more
+    than its own shard; the host staging (at most the padded slab) is freed
+    once every shard is on its device."""
+    S = mesh_shards(mesh)
+    _, row_bounds = leaf_cut(offs, S)
+    Tp, n = shard_rows(row_bounds), db.shape[1]
+    sharding = NamedSharding(mesh, P(data_axes(mesh), None, None))
+    out = {}
+    for d, ix in sharding.addressable_devices_indices_map((S, Tp, n)).items():
+        s = ix[0].start or 0
+        r0, r1 = row_bounds[s], row_bounds[s + 1]
+        rows = np.zeros((1, Tp, n), np.float32)
+        # ``order`` indexes the collection by construction: "clip" skips
+        # the bounds check, which with ``out`` costs an extra staging copy
+        np.take(db, order[r0:r1], axis=0, out=rows[0, :r1 - r0], mode="clip")
+        out[d] = jax.device_put(rows, d)
+    jax.block_until_ready(list(out.values()))
+    return out
 
 
 def _round_rows(rows: int) -> int:

@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import get_mesh
 from .build import DumpyParams
-from .device_index import data_axes
+from .device_index import data_axes, mesh_shards
 from .index import DumpyIndex
 from .sax import next_bit_codes_jnp, sax_encode_jnp
 
@@ -164,12 +164,11 @@ def lower_search_sharded(mesh, *, n_series: int = 1 << 22, length: int = 256,
     object; callers ``.compile()`` and harvest analyses."""
     from .device_index import abstract_device_index
     from .metric import ED
-    from .search_device import (_exact_knn_lane_sharded, _exact_knn_sharded,
-                                _mesh_shards)
+    from .search_device import _exact_knn_lane_sharded, _exact_knn_sharded
 
     met = metric or ED
     dev_abs = abstract_device_index(n_series, length, w,
-                                    n_shards=_mesh_shards(mesh),
+                                    n_shards=mesh_shards(mesh),
                                     chunk=chunk, n_leaves=n_leaves,
                                     shard_health=shard_health, mesh=mesh)
     # the same program selection as exact_search_device_batch: DTW with a
@@ -218,9 +217,8 @@ def lower_search_degraded(mesh, *, n_series: int = 1 << 22,
     ``shard_health`` is static aux data on the ``DeviceIndex``, so this is
     a separate specialization — the healthy program lowers byte-identically
     to :func:`lower_search_sharded` and keeps its own contract entry."""
-    from .search_device import _mesh_shards
 
-    S = _mesh_shards(mesh)
+    S = mesh_shards(mesh)
     health = (True,) * (S - 1) + (False,) if S > 1 else None
     return lower_search_sharded(mesh, n_series=n_series, length=length, w=w,
                                 chunk=chunk, n_leaves=n_leaves, k=k,
@@ -235,10 +233,10 @@ def lower_search_extended(mesh, *, n_series: int = 1 << 22, length: int = 256,
     sibling schedule + shard-local leaf scan) on ``mesh`` with production
     shardings.  Returns the jax ``Lowered`` object."""
     from .device_index import abstract_device_index
-    from .search_device import _extended_knn_sharded, _mesh_shards
+    from .search_device import _extended_knn_sharded
 
     dev_abs = abstract_device_index(n_series, length, w,
-                                    n_shards=_mesh_shards(mesh),
+                                    n_shards=mesh_shards(mesh),
                                     chunk=chunk, n_leaves=n_leaves,
                                     mesh=mesh)
     search_n = lambda d, prep, sq, q: _extended_knn_sharded(
@@ -261,11 +259,11 @@ def lower_search_approx(mesh, *, n_series: int = 1 << 22, length: int = 256,
     production shardings.  Returns the jax ``Lowered`` object."""
     from .device_index import abstract_device_index
     from .metric import ED
-    from .search_device import _approx_knn_device, _mesh_shards
+    from .search_device import _approx_knn_device
 
     met = metric or ED
     dev_abs = abstract_device_index(n_series, length, w,
-                                    n_shards=_mesh_shards(mesh),
+                                    n_shards=mesh_shards(mesh),
                                     chunk=chunk, n_leaves=n_leaves,
                                     mesh=mesh)
     approx_k = lambda d, prep, sq, q: _approx_knn_device(
@@ -294,10 +292,10 @@ def lower_search_bucket(mesh, *, n_series: int = 1 << 22, length: int = 256,
     that shape."""
     from .device_index import abstract_device_index
     from .metric import default_band
-    from .search_device import _bucket_knn_sharded, _mesh_shards
+    from .search_device import _bucket_knn_sharded
 
     dev_abs = abstract_device_index(n_series, length, w,
-                                    n_shards=_mesh_shards(mesh),
+                                    n_shards=mesh_shards(mesh),
                                     chunk=chunk, n_leaves=n_leaves,
                                     mesh=mesh)
     band_eff = band if band is not None else default_band(length)

@@ -306,6 +306,8 @@ class DumpyIndex:
         self._dirty = False
         self._db_ordered: np.ndarray | None = None
         self._db_ordered_dev = None            # device-resident copy, if any
+        # a mesh build's rows: (mesh, {device: [1, Tp, n] shard rows})
+        self._db_shards: tuple | None = None
         self._n_layout_builds = 0              # observability (tests)
         self._n_device_builds = 0              # cache-miss DeviceIndex builds
         # (chunk, n_shards, mesh) → (DeviceIndex, alive snapshot); keyed per
@@ -321,25 +323,33 @@ class DumpyIndex:
     # -- construction --------------------------------------------------------
     @classmethod
     def build(cls, db: np.ndarray, params: DumpyParams | None = None,
-              backend: str = "host") -> "DumpyIndex":
+              backend: str = "host", mesh=None) -> "DumpyIndex":
         """Build the index with either the host backend (reference Alg. 1
         recursion) or the device backend (bottom-up grouped build,
         ``core/build_device.py``).  Both produce the same layout up to the
         tie-breaking documented in ``docs/build_pipeline.md``.  The build
         is one ``dumpy.build`` span; the device backend's stages are its
-        children (``core/build_device.py``)."""
+        children (``core/build_device.py``).
+
+        ``mesh`` (device backend only) lays the ordered rows out shard by
+        shard on it, each on the device that owns it, so that
+        ``device_index(mesh=mesh)`` places them with no further copy."""
         params = params or DumpyParams()
         with obs.span("dumpy.build", backend=backend):
             db = np.ascontiguousarray(db, dtype=np.float32)
             if backend == "device":
                 from .build_device import device_build
-                res = device_build(db, params)
+                res = device_build(db, params, mesh=mesh)
                 idx = cls(params, res.root, res.flat, db, res.paa, res.sax,
                           res.stats)
                 idx._db_ordered_dev = res.db_ordered_dev
+                if mesh is not None:
+                    idx._db_shards = (mesh, res.db_shards)
                 return idx
             if backend != "host":
                 raise ValueError(f"unknown build backend: {backend!r}")
+            if mesh is not None:
+                raise ValueError("a mesh build needs backend='device'")
             builder = DumpyBuilder(params)
             root, stats, paa, sax = builder.build(db)
             flat = flatten_tree(root, params.sax.b)
@@ -368,6 +378,7 @@ class DumpyIndex:
         self._dirty = True
         self._db_ordered = None
         self._db_ordered_dev = None
+        self._db_shards = None
         self._routing_flat = None
         self._device_cache.clear()    # layout changed: device state is stale
 
@@ -498,15 +509,22 @@ class DumpyIndex:
         key = (int(chunk), int(n_shards), mesh)
         cached = self._device_cache.get(key)
         if cached is None:
-            # device-built indexes keep db_ordered on device: assemble the
-            # DeviceIndex from those rows without a host round-trip
+            # device-built indexes keep db_ordered on device (on a mesh,
+            # shard by shard): assemble the DeviceIndex from those rows
+            # without a host round-trip
             db_device = None if self._dirty else self._db_ordered_dev
+            db_shards = None
+            if not self._dirty and self._db_shards is not None:
+                built_on, shards = self._db_shards
+                if built_on == mesh and len(shards) == n_shards:
+                    db_shards = shards
 
             def _build():
                 failpoint("device.put")
                 return DeviceIndex.from_index(self, chunk=chunk,
                                               n_shards=n_shards,
-                                              db_device=db_device, mesh=mesh)
+                                              db_device=db_device,
+                                              db_shards=db_shards, mesh=mesh)
 
             # transient upload failures (device OOM races, injected faults)
             # are retried with backoff before giving up

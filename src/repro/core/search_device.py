@@ -85,7 +85,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .device_index import DeviceIndex, data_axes
+from .device_index import DeviceIndex, data_axes, mesh_shards
 from .index import DumpyIndex
 from .lb import (dtw2_masked_gather_jnp, dtw_np_batch, ed2_batch_jnp,
                  lb_improved2_batch_jnp, lb_keogh2_batch_jnp)
@@ -161,10 +161,13 @@ def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
 STAT_KEYS = ("considered", "killed_lb_keogh", "killed_lb_improved",
              "dp_abandoned")
 
-#: slots of the span walk's work vector (i32[5]), recorded per exact call
+#: slots of a shard's span-walk work vector (i32[5]), recorded per exact call
 #: as ``repro.obs`` counters of these names
 WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed",
              "exact.spans_merged", "exact.merge_rounds")
+#: counter beside them: the most spans one shard walked in the call (the
+#: shards merge only once the slowest is done)
+SPANS_WALKED_MAX = "exact.spans_walked_max"
 
 _exact_calls = itertools.count()
 
@@ -471,14 +474,15 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                        ) -> tuple[jax.Array, ...]:
     """Interval-MINDIST tables → per-shard span walks → all-gather merge
     with in-merge dedup.  Returns ``(d [Q,k], original ids [Q,k],
-    spans_visited [Q], cascade stats i32[4], work i32[5])`` with invalid
+    spans_visited [Q], cascade stats i32[4], work i32[6])`` with invalid
     slots as ``inf / -1`` (stats are all-zero for ED).  ``work`` counts the
     walk in :data:`WORK_KEYS` order, summed over shards (dead ones
     included: their walks still run): spans walked, their live rows
     (``w_size``), live rows × queries active at the span, spans at which
     some candidate entered a query's running top-k, and the merge's
     insertion rounds (per span the most candidates any query brings in,
-    capped at ``k``).
+    capped at ``k``); its last slot is :data:`SPANS_WALKED_MAX`, the most
+    spans any one shard walked.
 
     Per shard a vmapped prelude builds the span bounds (the metric's
     interval MINDIST, min over each span's leaves), the walk order shared
@@ -535,7 +539,7 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
     alli = jnp.moveaxis(topi, 0, 1).reshape(Q, S * k)       # sharded over S
     d2m, idm = _dedup_topk(alld, alli, k)
     return (jnp.sqrt(d2m), idm, vis.sum(axis=0), st.sum(axis=0),
-            work.sum(axis=0))
+            jnp.concatenate([work.sum(axis=0), work[:, :1].max(axis=0)]))
 
 
 def _cluster_groups(Q: int) -> int:
@@ -725,14 +729,6 @@ def _finalize_exact(index: DumpyIndex, qs: np.ndarray, ids_dev: np.ndarray,
     return out_ids, out_d
 
 
-def _mesh_shards(mesh) -> int:
-    s = 1
-    for ax in ("pod", "data"):
-        if mesh is not None and ax in mesh.axis_names:
-            s *= mesh.shape[ax]
-    return s
-
-
 def exact_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
                               chunk: int = 2048, mesh=None,
                               dev: DeviceIndex | None = None,
@@ -765,22 +761,25 @@ def exact_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
     (docs/robustness.md).
 
     Each call is a ``dumpy.exact.call`` span (attributes ``call``, ``Q``,
-    ``k``, ``chunk``, and ``scan``: ``"kernel"`` where the span-scan
-    kernel walks the spans, ``"loop"`` where an XLA loop does) over
-    ``dumpy.exact.prep``, ``.launch``, ``.wait`` and ``.finalize``, and
-    records the span walk's :data:`WORK_KEYS` counters; the DTW lane
-    program has none (docs/observability.md)."""
+    ``k``, ``chunk``, ``shards``, and ``scan``: ``"kernel"`` where the
+    span-scan kernel walks the spans, ``"loop"`` where an XLA loop does)
+    over ``dumpy.exact.prep``, ``.launch``, ``.wait`` and ``.finalize``,
+    and records the span walk's :data:`WORK_KEYS` counters and
+    :data:`SPANS_WALKED_MAX`; the DTW lane program has none
+    (docs/observability.md)."""
     Q = np.shape(qs)[0] if np.ndim(qs) == 2 else 1
     met = resolve(metric, index.n, band, order)
     scan = "kernel" if ops.span_scan_kernel(index.n) and not met.is_dtw \
         else "loop"
     with obs.span("dumpy.exact.call", call=next(_exact_calls), Q=Q, k=k,
-                  chunk=chunk if dev is None else dev.chunk, scan=scan):
+                  chunk=chunk if dev is None else dev.chunk,
+                  shards=mesh_shards(mesh) if dev is None else dev.n_shards,
+                  scan=scan):
         with obs.span("dumpy.exact.prep"):
             qs = _validate_queries(qs, index.n)
             if dev is None:
                 dev = index.device_index(chunk=chunk,
-                                         n_shards=_mesh_shards(mesh),
+                                         n_shards=mesh_shards(mesh),
                                          mesh=mesh)
             want_cov = shard_health is not None or \
                 dev.shard_health is not None
@@ -808,7 +807,7 @@ def exact_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
             ids, visited, work, st = jax.device_get(
                 (ids, visited, work, st if return_stats else None))
         if work is not None:
-            for name, v in zip(WORK_KEYS, work):
+            for name, v in zip(WORK_KEYS + (SPANS_WALKED_MAX,), work):
                 obs.count(name, v)
         with obs.span("dumpy.exact.finalize"):
             ids_out, d_out = _finalize_exact(index, qs, ids, k, met)
@@ -1214,7 +1213,7 @@ def extended_search_device_batch(index: DumpyIndex, qs: np.ndarray, k: int,
     qs = _validate_queries(qs, index.n)
     met = resolve(metric, qs.shape[1], band)
     if dev is None:
-        dev = index.device_index(chunk=chunk, n_shards=_mesh_shards(mesh),
+        dev = index.device_index(chunk=chunk, n_shards=mesh_shards(mesh),
                                  mesh=mesh)
     want_cov = shard_health is not None or dev.shard_health is not None
     if shard_health is not None:
@@ -1500,7 +1499,7 @@ def bucket_search_device_batch(index: DumpyIndex, qs, ks, nbrs,
         raise ValueError(
             f"lanes {over[:8].tolist()} request k > k_max={k_max}")
     if dev is None:
-        dev = index.device_index(chunk=chunk, n_shards=_mesh_shards(mesh),
+        dev = index.device_index(chunk=chunk, n_shards=mesh_shards(mesh),
                                  mesh=mesh)
     want_cov = shard_health is not None or dev.shard_health is not None
     if shard_health is not None:

@@ -133,6 +133,7 @@ def test_exact_call_spans_nest_and_share_the_device_timeline(index,
     call = [s for s in got if s.name == "dumpy.exact.call"]
     assert len(call) == 1 and call[0].attrs["Q"] == 8
     assert call[0].attrs["k"] == 5 and call[0].attrs["chunk"] == 128
+    assert call[0].attrs["shards"] == 1
     # ED walks its spans in the kernel on a TPU, in the XLA loop elsewhere
     assert call[0].attrs["scan"] == (
         "kernel" if sd.ops.span_scan_kernel(64) else "loop")
@@ -171,15 +172,17 @@ def _replay(index, dev, qs, kk):
     """The span loop of ``_exact_knn_sharded`` on the host, per shard, from
     the program's own schedule: ``(spans walked, live rows, live rows ×
     active queries, spans at which a candidate entered a running top-k,
-    merge rounds, visited [Q])`` summed over shards."""
+    merge rounds, visited [Q])`` summed over shards, and the most spans
+    one shard walked."""
     sax = index.params.sax
     prep, _ = sd._prep_batch(sd.ED, jax.numpy.asarray(qs), sax.w, sax.b)
     lb_g = np.asarray(sd._interval_lb(dev, prep[0], prep[1],
                                       dev.leaf_lo_g, dev.leaf_hi_g))
     Q = qs.shape[0]
-    walked = rows = pairs = merged = rounds = 0
+    walked = rows = pairs = merged = rounds = most = 0
     vis = np.zeros(Q, np.int64)
     for s in range(dev.db.shape[0]):
+        before = walked
         gid = np.asarray(dev.leaf_gid[s])
         lbq = np.where(gid[None, :] >= 0, lb_g[:, np.maximum(gid, 0)],
                        np.inf)
@@ -214,7 +217,8 @@ def _replay(index, dev, qs, kk):
             pairs += int(size[i]) * int(qact.sum())
             vis += qact
             i += 1
-    return walked, rows, pairs, merged, rounds, vis
+        most = max(most, walked - before)
+    return walked, rows, pairs, merged, rounds, vis, most
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -228,12 +232,13 @@ def test_loop_counters_equal_a_host_replay(index, n_shards, k, Q):
     _, _, visited = sd.exact_search_device_batch(index, qs, k, dev=dev)
     _, cnt = _since(m)
     got = {c.name: c.n for c in cnt}
-    assert set(got) == set(sd.WORK_KEYS)
-    walked, rows, pairs, merged, rounds, vis = _replay(index, dev, qs, k + 8)
+    assert set(got) == set(sd.WORK_KEYS) | {sd.SPANS_WALKED_MAX}
+    walked, rows, pairs, merged, rounds, vis, most = _replay(index, dev, qs,
+                                                             k + 8)
     assert (got["exact.spans_walked"], got["exact.rows_live"],
             got["exact.pairs_needed"], got["exact.spans_merged"],
-            got["exact.merge_rounds"]) == \
-        (walked, rows, pairs, merged, rounds)
+            got["exact.merge_rounds"], got[sd.SPANS_WALKED_MAX]) == \
+        (walked, rows, pairs, merged, rounds, most)
     np.testing.assert_array_equal(visited, vis)
     real = int((np.asarray(dev.win_size) > 0).sum())
     if k >= 4000:
