@@ -161,10 +161,11 @@ def _prep_batch(metric: Metric, qs_dev: jax.Array, w: int, b: int
 STAT_KEYS = ("considered", "killed_lb_keogh", "killed_lb_improved",
              "dp_abandoned")
 
-#: slots of a shard's span-walk work vector (i32[5]), recorded per exact call
+#: slots of a shard's span-walk work vector (i32[6]), recorded per exact call
 #: as ``repro.obs`` counters of these names
 WORK_KEYS = ("exact.spans_walked", "exact.rows_live", "exact.pairs_needed",
-             "exact.spans_merged", "exact.merge_rounds")
+             "exact.spans_merged", "exact.merge_rounds",
+             "exact.spans_collided")
 #: counter beside them: the most spans one shard walked in the call (the
 #: shards merge only once the slowest is done)
 SPANS_WALKED_MAX = "exact.spans_walked_max"
@@ -396,8 +397,10 @@ def span_loop(metric: Metric, qs: jax.Array, prep: tuple, db_s: jax.Array,
     ``w_start/w_lead/w_size [W]`` is the walk-ordered schedule, ``win_lb
     [Q, W]`` the span bounds in that order and ``suffix [Q, W+1]`` their
     suffix minima with a ``+inf`` column appended.  Returns ``(topd [Q, k],
-    topi [Q, k], vis [Q], cascade stats i32[4], work i32[5])``, ``work`` in
-    :data:`WORK_KEYS` order; only the last three are carried.
+    topi [Q, k], vis [Q], cascade stats i32[4], work i32[6])``, ``work`` in
+    :data:`WORK_KEYS` order; only the last four are carried.  The ED walk
+    counts the spans whose candidates collide in a 128-column lane
+    (:func:`ops.lanes_collide`), as the kernel does; DTW counts none.
 
     Each span (each DTW sub-slab) merges through
     :func:`ops.topk_merge_cutoff`.  DTW sub-blocks the slab with an inner
@@ -419,12 +422,12 @@ def span_loop(metric: Metric, qs: jax.Array, prep: tuple, db_s: jax.Array,
         return (i < W) & jnp.any(suffix[:, i] < topd[:, k - 1])
 
     def body(carry):
-        i, topd, topi, vis, st, pairs, merged, rounds = carry
+        i, topd, topi, vis, st, pairs, merged, rounds, collided = carry
         start = w_start[i]
         qact = win_lb[:, i] < topd[:, k - 1]                # [Q] active mask
 
         def sub(b, c2):
-            topd, topi, st, hit, rounds = c2
+            topd, topi, st, hit, rounds, collided = c2
             s0 = start + b * sub_w
             # pin the literal column index to int32: under an x64 env a
             # bare 0 defaults to int64 and dynamic_slice rejects the mixed
@@ -441,31 +444,35 @@ def span_loop(metric: Metric, qs: jax.Array, prep: tuple, db_s: jax.Array,
                                   valid[None, :] & qact_b[:, None],
                                   topd[:, k - 1])
             sid = jax.lax.dynamic_slice(ids_s, (s0,), (sub_w,))
+            if not metric.is_dtw:
+                collided = collided + ops.lanes_collide(topd, d2)
             topd, topi, m = ops.topk_merge_cutoff(topd, topi, d2, sid,
                                                   SHARD_AXIS)
-            return topd, topi, st + stt, hit | (m > 0), rounds + m
+            return topd, topi, st + stt, hit | (m > 0), rounds + m, collided
 
-        c2 = (topd, topi, st, jnp.bool_(False), rounds)
+        c2 = (topd, topi, st, jnp.bool_(False), rounds, collided)
         if n_sub == 1:
-            topd, topi, st, hit, rounds = sub(0, c2)
+            topd, topi, st, hit, rounds, collided = sub(0, c2)
         else:
-            topd, topi, st, hit, rounds = jax.lax.fori_loop(0, n_sub, sub, c2)
+            topd, topi, st, hit, rounds, collided = jax.lax.fori_loop(
+                0, n_sub, sub, c2)
         act = qact.astype(jnp.int32)
         pairs = pairs + w_size[i] * act.sum(dtype=jnp.int32)
         return (i + 1, topd, topi, vis + act, st, pairs,
-                merged + hit.astype(jnp.int32), rounds)
+                merged + hit.astype(jnp.int32), rounds, collided)
 
     init = (jnp.int32(0),
             jnp.full((Q, k), jnp.inf, jnp.float32),
             jnp.full((Q, k), -1, jnp.int32),
             jnp.zeros((Q,), jnp.int32),
             jnp.zeros(4, jnp.int32),
-            jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    i, topd, topi, vis, st, pairs, merged, rounds = jax.lax.while_loop(
-        cond, body, init)
+            jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0))
+    i, topd, topi, vis, st, pairs, merged, rounds, collided = \
+        jax.lax.while_loop(cond, body, init)
     # the walk is a prefix of the order: its rows need no carry
     rows = jnp.where(jnp.arange(W) < i, w_size, 0).sum(dtype=jnp.int32)
-    return topd, topi, vis, st, jnp.stack([i, rows, pairs, merged, rounds])
+    return topd, topi, vis, st, jnp.stack([i, rows, pairs, merged, rounds,
+                                           collided])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
@@ -474,15 +481,16 @@ def _exact_knn_sharded(dev: DeviceIndex, prep: tuple, qs: jax.Array, *,
                        ) -> tuple[jax.Array, ...]:
     """Interval-MINDIST tables → per-shard span walks → all-gather merge
     with in-merge dedup.  Returns ``(d [Q,k], original ids [Q,k],
-    spans_visited [Q], cascade stats i32[4], work i32[6])`` with invalid
+    spans_visited [Q], cascade stats i32[4], work i32[7])`` with invalid
     slots as ``inf / -1`` (stats are all-zero for ED).  ``work`` counts the
     walk in :data:`WORK_KEYS` order, summed over shards (dead ones
     included: their walks still run): spans walked, their live rows
     (``w_size``), live rows × queries active at the span, spans at which
     some candidate entered a query's running top-k, and the merge's
     insertion rounds (per span the most candidates any query brings in,
-    capped at ``k``); its last slot is :data:`SPANS_WALKED_MAX`, the most
-    spans any one shard walked.
+    capped at ``k``) and the merged spans at which some query holds two
+    candidates in one 128-column lane; its last slot is
+    :data:`SPANS_WALKED_MAX`, the most spans any one shard walked.
 
     Per shard a vmapped prelude builds the span bounds (the metric's
     interval MINDIST, min over each span's leaves), the walk order shared

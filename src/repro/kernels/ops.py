@@ -102,7 +102,7 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
               size: jax.Array, win_lb: jax.Array, suffix: jax.Array, *,
               kk: int, chunk: int) -> tuple[jax.Array, ...]:
     """The exact ED span walk of every shard in ``db [S, Tp, n]``, in walk
-    order → ``(topd [S, Q, kk], topi [S, Q, kk], vis [S, Q], work [S, 5])``
+    order → ``(topd [S, Q, kk], topi [S, Q, kk], vis [S, Q], work [S, 6])``
     (arguments and outputs as ``span_scan.span_scan``).  Pallas kernel on
     TPU (where :func:`span_scan_kernel`); otherwise the XLA ``while_loop``
     it replaces (``search_device.span_loop``, vmapped over the shards)."""
@@ -158,6 +158,17 @@ def _flip(b: jax.Array) -> jax.Array:
 
 def _order_key(x: jax.Array) -> jax.Array:
     return _flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def lanes_collide(topd: jax.Array, d2: jax.Array) -> jax.Array:
+    """Whether some query holds two or more candidates below its running
+    ``k``-th best (``topd [Q, k]``) in one 128-column lane of ``d2 [Q,
+    C]``, ``C`` a multiple of 128: the spans at which the span-scan
+    kernel's lane fold may need rebuilding.  An i32 0 or 1."""
+    Q, C = d2.shape
+    below = _order_key(d2) < _order_key(topd[:, -1:])
+    per_lane = below.reshape(Q, C // 128, 128).sum(axis=1, dtype=jnp.int32)
+    return (per_lane.max() > 1).astype(jnp.int32)
 
 
 def topk_merge_cutoff(topd: jax.Array, topi: jax.Array, d2: jax.Array,

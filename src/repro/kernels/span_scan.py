@@ -27,14 +27,23 @@ from the first span to the last.
   rounds of ``ops.topk_merge_cutoff``: each takes every query's least
   remaining candidate (lowest column on ties) and inserts it after the held
   entries it does not lie below.  ``m <= kk``, so no span needs a sort, and
-  the result is bitwise ``ops.topk_merge`` of the same distances.
+  the result is bitwise ``ops.topk_merge`` of the same distances.  The
+  rounds read a lane fold of the span's candidates, not the ``[Q, chunk]``
+  block: per query and lane the least key below the cut over the lane's
+  128-column tiles, its column and how many of the lane's keys lie below
+  the cut.  A round takes the least (key, column) of the fold and pops
+  it; the fold is rebuilt from the block, past each query's last taken
+  entry, only after a query takes an entry whose lane held another
+  candidate.  The kernel holds each entry's row in its shard; the
+  wrapper maps rows to ids.
 * **Termination.**  The loop's own condition, ``any(suffix[:, i] <
   topd[:, kk-1])``, read at every step.
 
 Outputs are the loop's: ``topd/topi [S, Q, kk]``, ``vis [S, Q]`` (spans a
-query was active at) and ``work [S, 5]`` (``WORK_KEYS`` order: spans
+query was active at) and ``work [S, 6]`` (``WORK_KEYS`` order: spans
 walked, their live rows, live rows × active queries, spans at which a
-candidate entered, merge rounds).
+candidate entered, merge rounds, merged spans at which some query held two
+candidates in one lane).
 """
 from __future__ import annotations
 
@@ -70,8 +79,8 @@ def _column(blk: jax.Array, c, fill) -> jax.Array:
 
 def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
             nx_ref, id_ref, db_ref, topd_ref, topi_ref, vis_ref, work_ref,
-            buf, sem, idb, id_sem, key_ref, st_ref, *, kk: int, chunk: int,
-            W: int):
+            buf, sem, idb, id_sem, key_ref, fold_ref, st_ref, *, kk: int,
+            chunk: int, W: int):
     s, i = pl.program_id(0), pl.program_id(1)
     Q, KP = topd_ref.shape
     C = chunk
@@ -98,7 +107,7 @@ def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
         topd_ref[...] = jnp.full((Q, KP), jnp.inf, jnp.float32)
         topi_ref[...] = jnp.full((Q, KP), -1, jnp.int32)
         vis_ref[...] = jnp.zeros((Q, 1), jnp.int32)
-        for c in range(8):                 # pending copies 0-1, counters 2-6
+        for c in range(8):                 # pending copies 0-1, counters 2-7
             st_ref[c] = 0
 
     cut = topd_ref[:, kk - 1:kk]                                 # [Q, 1]
@@ -131,6 +140,7 @@ def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
             preferred_element_type=jnp.float32)                  # [Q, C]
         xn = jnp.sum(slab * slab, axis=1)[None, :]               # [1, C]
         d2 = jnp.maximum((qn_ref[...] + xn) - 2.0 * cross, 0.0)
+        r0 = start_ref[s * W + i]
         lead, size = lead_ref[s * W + i], size_ref[s * W + i]
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
         ids = jnp.where((lane >= lead) & (lane < lead + size), idb[slot],
@@ -141,37 +151,80 @@ def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
         key_ref[...] = dk
         cnt = jnp.sum((dk < cutk).astype(jnp.int32), axis=1, keepdims=True)
         m = jnp.minimum(jnp.max(cnt), kk)
-
-        col = jax.lax.broadcasted_iota(jnp.int32, (Q, C), 1)
+        l128 = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
         slot_i = jax.lax.broadcasted_iota(jnp.int32, (Q, KP), 1)
 
-        def round_(_, carry):
-            lk, lc = carry
-            dk = key_ref[...]
-            after = (dk > lk) | ((dk == lk) & (col > lc))
-            k2 = jnp.where((dk < cutk) & after, dk, _I32_MAX)
-            vk = jnp.min(k2, axis=1, keepdims=True)              # [Q, 1]
-            j = jnp.min(jnp.where(k2 == vk, col, C), axis=1, keepdims=True)
-            vi = jnp.max(jnp.where(col == j, ids, -1), axis=1, keepdims=True)
+        def fold(last=None):
+            """Fold the block's keys below the cut, past each query's last
+            taken ``(key, column)`` where ``last`` is given, onto 128 lanes:
+            the least key of each lane (the lowest tile on ties), its
+            column and the lane's count."""
+            f = jnp.full((Q, 128), _I32_MAX, jnp.int32)
+            g = jnp.full((Q, 128), C, jnp.int32)
+            n_ = jnp.zeros((Q, 128), jnp.int32)
+            for t in range(C // 128):
+                kt = key_ref[:, t * 128:(t + 1) * 128]
+                ct = l128 + t * 128
+                ok = kt < cutk
+                if last is not None:
+                    lk, lc = last
+                    ok &= (kt > lk) | ((kt == lk) & (ct > lc))
+                lt = ok & (kt < f)
+                f = jnp.where(lt, kt, f)
+                g = jnp.where(lt, ct, g)
+                n_ += ok.astype(jnp.int32)
+            fold_ref[0], fold_ref[1], fold_ref[2] = f, g, n_
+            return n_
+
+        def round_(refold, _, carry):
+            f, g = fold_ref[0], fold_ref[1]
+            vk = jnp.min(f, axis=1, keepdims=True)               # [Q, 1]
+            j = jnp.min(jnp.where(f == vk, g, C), axis=1, keepdims=True)
+            hit = g == j
             td, ti = topd_ref[...], topi_ref[...]
             tk = _key(td)
-            pos = jnp.sum(((tk <= vk) & (slot_i < kk)).astype(jnp.int32),
-                          axis=1, keepdims=True)
-            take = vk < tk[:, kk - 1:kk]
+            # the held entries the candidate does not lie below: a prefix
+            # of the kk slots, since they are held in key order.  A query
+            # whose candidate does not enter it inserts past the kk-th
+            # slot, which the outputs drop
+            le = (tk <= vk) & (slot_i < kk)
+            at = jnp.logical_not(le) & (
+                (slot_i == 0) | (pltpu.roll(le.astype(jnp.int32), 1, 1) > 0))
 
             def ins(t, x):
-                prev = pltpu.roll(t, 1, 1)
-                new = jnp.where(slot_i < pos, t,
-                                jnp.where(slot_i == pos, x, prev))
-                return jnp.where(take, new, t)
+                return jnp.where(le, t,
+                                 jnp.where(at, x, pltpu.roll(t, 1, 1)))
 
             topd_ref[...] = ins(td, _unkey(vk))
-            topi_ref[...] = ins(ti, vi)
-            return vk, j
+            topi_ref[...] = ins(ti, j + r0)
+            # pop the entry: a lane that held another candidate hides it
+            # until the fold is rebuilt past the entry.  A query whose
+            # entry did not enter takes nothing more in this span (its
+            # next candidates lie no lower), so only takers call for that
+            fold_ref[0] = jnp.where(hit, _I32_MAX, f)
+            if refold:
+                take = vk < tk[:, kk - 1:kk]
 
-        jax.lax.fori_loop(0, m, round_,
-                          (jnp.full((Q, 1), -_I32_MAX - 1, jnp.int32),
-                           jnp.full((Q, 1), -1, jnp.int32)))
+                @pl.when(jnp.any(take & hit & (fold_ref[2] > 1)))
+                def _refold():
+                    fold((vk, j))
+
+            return carry
+
+        @pl.when(m > 0)
+        def _merge():
+            collided = jnp.max(fold()) > 1
+            st_ref[7] += collided.astype(jnp.int32)
+
+            # with one candidate a lane at most, no round can refold
+            @pl.when(collided)
+            def _rounds_refold():
+                jax.lax.fori_loop(0, m, functools.partial(round_, True), 0)
+
+            @pl.when(jnp.logical_not(collided))
+            def _rounds():
+                jax.lax.fori_loop(0, m, functools.partial(round_, False), 0)
+
         act = qact.astype(jnp.int32)
         vis_ref[...] += act
         st_ref[2] += 1
@@ -189,7 +242,7 @@ def _kernel(start_ref, lead_ref, size_ref, q_ref, qn_ref, lb_ref, sf_ref,
     def _out():
         lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
         w = jnp.zeros((8, 128), jnp.int32)
-        for c in range(5):
+        for c in range(6):
             w = jnp.where(lane == c, st_ref[2 + c], w)
         work_ref[...] = w
 
@@ -215,7 +268,7 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
     of 128 rows, as ``DeviceIndex`` lays them out); ``win_lb [S, Q,
     W]`` the span bounds in walk order and ``suffix [S, Q, W+1]`` their
     suffix minima with a ``+inf`` column appended.  Returns ``(topd [S, Q,
-    kk], topi [S, Q, kk], vis [S, Q], work [S, 5])``.  A batch whose
+    kk], topi [S, Q, kk], vis [S, Q], work [S, 6])``.  A batch whose
     ``[Q, chunk]`` scores exceed :data:`MAX_BLOCK` is walked in query
     groups; the counters then sum over the groups."""
     Q = qs.shape[0]
@@ -247,7 +300,7 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
 
     n_l = -(-n // 128) * 128
     Q8 = -(-Q // 8) * 8
-    vmem = 4 * (2 * C * n_l + 16 * C + 8 * Q8 * C + 6 * Q8 * 128
+    vmem = 4 * (2 * C * n_l + 16 * C + 8 * Q8 * C + 9 * Q8 * 128
                 + 4 * Q8 * KP + 2 * Q8 * n_l) + (4 << 20)
     table = pl.BlockSpec((None, None, Q, 128),
                          lambda s, i, *_: (s, i // 128, 0, 0))
@@ -275,6 +328,7 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
                 pltpu.VMEM((2, 1, C), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((Q, C), jnp.int32),
+                pltpu.VMEM((3, Q, 128), jnp.int32),
                 pltpu.SMEM((8,), jnp.int32),
             ]),
         out_shape=[
@@ -290,5 +344,9 @@ def span_scan(qs: jax.Array, db: jax.Array, alive: jax.Array,
         name="span_scan",
     )(start.reshape(-1), lead.reshape(-1), size.reshape(-1), qs, qn, lb,
       sf, nx, idv, db)
-    return (topd[:, :, :kk], topi[:, :, :kk], vis[:, :, 0],
-            work[:, 0, :5])
+    # the kernel holds each entry's row in its shard: map rows to ids
+    rows = topi[:, :, :kk]
+    held = jnp.take_along_axis(idv, jnp.maximum(rows, 0).reshape(S, 1, -1),
+                               axis=2).reshape(rows.shape)
+    return (topd[:, :, :kk], jnp.where(rows >= 0, held, -1), vis[:, :, 0],
+            work[:, 0, :6])

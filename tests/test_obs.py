@@ -172,14 +172,15 @@ def _replay(index, dev, qs, kk):
     """The span loop of ``_exact_knn_sharded`` on the host, per shard, from
     the program's own schedule: ``(spans walked, live rows, live rows ×
     active queries, spans at which a candidate entered a running top-k,
-    merge rounds, visited [Q])`` summed over shards, and the most spans
+    merge rounds, spans at which some query held two candidates in one
+    128-column lane, visited [Q])`` summed over shards, and the most spans
     one shard walked."""
     sax = index.params.sax
     prep, _ = sd._prep_batch(sd.ED, jax.numpy.asarray(qs), sax.w, sax.b)
     lb_g = np.asarray(sd._interval_lb(dev, prep[0], prep[1],
                                       dev.leaf_lo_g, dev.leaf_hi_g))
     Q = qs.shape[0]
-    walked = rows = pairs = merged = rounds = most = 0
+    walked = rows = pairs = merged = rounds = collided = most = 0
     vis = np.zeros(Q, np.int64)
     for s in range(dev.db.shape[0]):
         before = walked
@@ -208,8 +209,13 @@ def _replay(index, dev, qs, kk):
             slab = db_s[r0:r0 + size[i]]
             d2 = ((qs[:, None, :] - slab[None]) ** 2).sum(-1)
             d2 = np.where(qact[:, None], d2, np.inf)
-            hits = (d2 < topd[:, -1:]).sum(axis=1)
+            below = d2 < topd[:, -1:]
+            hits = below.sum(axis=1)
             merged += bool(hits.any())
+            lane = (lead[i] + np.arange(size[i])) % 128
+            per_lane = np.stack([np.bincount(lane[b], minlength=128)
+                                 for b in below])
+            collided += bool((per_lane > 1).any())
             rounds += min(int(hits.max()), kk)
             topd = np.sort(np.concatenate([topd, d2], 1), 1)[:, :kk]
             walked += 1
@@ -218,7 +224,7 @@ def _replay(index, dev, qs, kk):
             vis += qact
             i += 1
         most = max(most, walked - before)
-    return walked, rows, pairs, merged, rounds, vis, most
+    return walked, rows, pairs, merged, rounds, collided, vis, most
 
 
 @pytest.mark.parametrize("n_shards", [1, 4])
@@ -233,12 +239,14 @@ def test_loop_counters_equal_a_host_replay(index, n_shards, k, Q):
     _, cnt = _since(m)
     got = {c.name: c.n for c in cnt}
     assert set(got) == set(sd.WORK_KEYS) | {sd.SPANS_WALKED_MAX}
-    walked, rows, pairs, merged, rounds, vis, most = _replay(index, dev, qs,
-                                                             k + 8)
+    walked, rows, pairs, merged, rounds, collided, vis, most = _replay(
+        index, dev, qs, k + 8)
     assert (got["exact.spans_walked"], got["exact.rows_live"],
             got["exact.pairs_needed"], got["exact.spans_merged"],
-            got["exact.merge_rounds"], got[sd.SPANS_WALKED_MAX]) == \
-        (walked, rows, pairs, merged, rounds, most)
+            got["exact.merge_rounds"], got["exact.spans_collided"],
+            got[sd.SPANS_WALKED_MAX]) == \
+        (walked, rows, pairs, merged, rounds, collided, most)
+    assert collided == 0                     # one tile a span
     np.testing.assert_array_equal(visited, vis)
     real = int((np.asarray(dev.win_size) > 0).sum())
     if k >= 4000:
@@ -247,6 +255,26 @@ def test_loop_counters_equal_a_host_replay(index, n_shards, k, Q):
     elif n_shards == 1:
         assert walked < real                 # the test exercises pruning
         assert 0 < merged < walked           # and spans that change nothing
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+@pytest.mark.parametrize("k,Q", [(3, 2), (4000, 8)])
+def test_lane_collisions_equal_a_host_replay(index, n_shards, k, Q):
+    """Spans of two tiles: ``exact.spans_collided`` counts the merged spans
+    at which some query holds two candidates below its cut 128 columns
+    apart, as the host replay does."""
+    qs = random_walks(Q, 64, seed=9).astype(np.float32)
+    dev = index.device_index(chunk=256, n_shards=n_shards)
+    m = obs.mark()
+    sd.exact_search_device_batch(index, qs, k, dev=dev)
+    got = {c.name: c.n for c in _since(m)[1]}
+    _, _, _, merged, rounds, collided, _, _ = _replay(index, dev, qs, k + 8)
+    assert (got["exact.spans_merged"], got["exact.merge_rounds"],
+            got["exact.spans_collided"]) == (merged, rounds, collided)
+    if k >= 4000:
+        assert collided == merged            # every lane full at cut +inf
+    else:
+        assert 0 < collided < merged
 
 
 def test_lane_program_records_no_loop_counters(index):

@@ -64,30 +64,50 @@ def _walk_inputs(dev, qs):
     return (jnp.asarray(qs), dev.db, dev.alive, dev.ids) + tuple(sched)
 
 
-# (Q, kk, fuzzy layout, tombstones, shards, chunk)
+def _pair_tiles(db, eps):
+    """Each odd 128-row tile of ``db [S, Tp, n]`` made the even tile before
+    it plus ``eps`` × its tile number, so that a span's lanes hold equal
+    (``eps`` 0) or near-equal rows 128 columns apart."""
+    db = np.asarray(db).copy()
+    tile = np.arange(db.shape[1]) // 128
+    odd = np.flatnonzero(tile % 2 == 1)
+    db[:, odd] = db[:, odd - 128] + np.float32(eps) * tile[odd, None]
+    return jnp.asarray(db)
+
+
+# (Q, kk, fuzzy layout, tombstones, shards, chunk, paired tiles' offset)
 CASES = {
-    "q1": (1, 18, False, False, 1, 256),
-    "q7": (7, 18, False, True, 1, 256),
-    "q64": (64, 18, False, False, 1, 512),
-    "fuzzy_kk48": (7, 48, True, True, 1, 256),
-    "fuzzy_q64_kk48": (64, 48, True, False, 1, 512),
-    "clamped_lead": (7, 18, False, True, 1, 640),
-    "two_shards": (7, 18, False, True, 2, 256),
-    "q1_stops_early": (1, 18, False, False, 1, 128),
+    "q1": (1, 18, False, False, 1, 256, None),
+    "q7": (7, 18, False, True, 1, 256, None),
+    "q64": (64, 18, False, False, 1, 512, None),
+    "fuzzy_kk48": (7, 48, True, True, 1, 256, None),
+    "fuzzy_q64_kk48": (64, 48, True, False, 1, 512, None),
+    "clamped_lead": (7, 18, False, True, 1, 640, None),
+    "two_shards": (7, 18, False, True, 2, 256, None),
+    "q1_stops_early": (1, 18, False, False, 1, 128, None),
     # shard row counts off the 128-row tile: the layout rounds them up, so
     # every span, the end-clamped last one too, starts on a tile
-    "fuzzy_two_shards_c384": (7, 18, True, True, 2, 384),
-    "fuzzy_three_shards_c384": (64, 48, True, True, 3, 384),
+    "fuzzy_two_shards_c384": (7, 18, True, True, 2, 384, None),
+    "fuzzy_three_shards_c384": (64, 48, True, True, 3, 384, None),
+    # lane collisions: rows 128 columns apart equal (ties taken by column)
+    # or nearly so, so a query's lane holds several candidates at every
+    # merged span and the merge rebuilds its lane fold
+    "equal_tiles": (7, 18, False, True, 1, 512, 0.0),
+    "near_equal_tiles_q64_kk48": (64, 48, False, False, 1, 512, 1e-3),
+    # one tile a span: no lane ever holds two candidates, no fold rebuilt
+    "q64_one_tile": (64, 18, False, True, 1, 128, None),
 }
 
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_kernel_walks_as_the_loop(case):
-    Q, kk, fuzzy, tomb, S, chunk = CASES[case]
+    Q, kk, fuzzy, tomb, S, chunk, eps = CASES[case]
     idx = _index(fuzzy, tomb)
     dev = idx.device_index(chunk=chunk, n_shards=S)
     qs = random_walks(Q, N, seed=11 + Q).astype(np.float32)
     args = _walk_inputs(dev, qs)
+    if eps is not None:
+        args = args[:1] + (_pair_tiles(args[1], eps),) + args[2:]
     loop = functools.partial(sd.span_loop, sd.ED, args[0], None, k=kk,
                              chunk=dev.chunk)
     want = jax.vmap(loop, axis_name=sd.SHARD_AXIS)(*args[1:])
@@ -101,14 +121,28 @@ def test_kernel_walks_as_the_loop(case):
         # merge rounds count candidates below a query's cut, and the two
         # sum d² in their own orders (``_close``): where replicas of one
         # series sit at the cut, a span can take a round more or fewer.
-        # The walk itself (spans, rows, pairs, spans merged) is exact.
+        # The walk itself (spans, rows, pairs, spans merged) is exact, and
+        # so is the count of spans whose candidates collide in a lane.
         np.testing.assert_array_equal(g_work[:, :4], w_work[:, :4])
         assert (np.abs(g_work[:, 4] - w_work[:, 4]) <= g_work[:, 3]).all()
+        np.testing.assert_array_equal(g_work[:, 5], w_work[:, 5])
     else:
         np.testing.assert_array_equal(g_work, w_work)
     work = dict(zip(sd.WORK_KEYS, g_work.sum(axis=0)))
     real = int((np.asarray(dev.win_size) > 0).sum())
     assert work["exact.merge_rounds"] >= work["exact.spans_merged"] > 0
+    # the first span meets every query at cut +inf: with two tiles or
+    # more a span, its lanes collide
+    collided = work["exact.spans_collided"]
+    if chunk == 128:
+        assert collided == 0
+    elif eps is not None:
+        assert collided == work["exact.spans_merged"]
+    else:
+        assert 0 < collided <= work["exact.spans_merged"]
+    if case in ("q7", "fuzzy_kk48", "two_shards"):
+        # later spans bring a query few candidates, in distinct lanes
+        assert collided < work["exact.spans_merged"]
     if case == "clamped_lead":
         assert (np.asarray(dev.win_lead) > 0).any()
     if case.endswith("_c384"):
@@ -136,6 +170,28 @@ def test_kernel_walks_as_the_loop(case):
         _close(outs[1][0], outs[0][0], qs, dev)
         for a, b in zip(outs[0][1:], outs[1][1:]):
             np.testing.assert_array_equal(a, b)
+
+
+def test_kernel_leaves_unfilled_slots_as_the_loop():
+    """Fewer live rows than ``kk``: the kernel holds rows in its shard and
+    maps them to ids after the walk; slots no candidate filled keep
+    ``+inf`` and ``-1``, as the loop's do."""
+    dev = _index(False, True).device_index(chunk=256, n_shards=2)
+    qs = random_walks(7, N, seed=23).astype(np.float32)
+    args = _walk_inputs(dev, qs)
+    alive = args[2] & (args[3] % 397 == 5)
+    args = args[:2] + (alive,) + args[3:]
+    kk = 18
+    loop = functools.partial(sd.span_loop, sd.ED, args[0], None, k=kk,
+                             chunk=dev.chunk)
+    want = jax.vmap(loop, axis_name=sd.SHARD_AXIS)(*args[1:])
+    got = _kernel(*args, kk=kk, chunk=dev.chunk)
+    _close(got[0], want[0], qs, dev)
+    for g, w in zip(got[1:3], want[1:3]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    topi = np.asarray(got[1])
+    assert ((topi == -1) == np.isinf(np.asarray(got[0]))).all()
+    assert 0 < (topi >= 0).sum(-1).max() < kk
 
 
 @pytest.mark.parametrize("fuzzy", [False, True])
